@@ -863,11 +863,12 @@ class CheckpointManager:
             self._read_step_into(m, bufs)
         out: Dict[str, np.ndarray] = {}
         for leaf in top["leaves"]:
+            # CRC and view the read buffer in place: no host copy per leaf
             buf = bufs[leaf["name"]]
-            if check_crc and zlib.crc32(bytes(buf)) != leaf["crc32"]:
+            if check_crc and zlib.crc32(buf) != leaf["crc32"]:
                 raise CheckpointError(f"crc mismatch for leaf {leaf['name']}")
             out[leaf["name"]] = np.frombuffer(
-                bytes(buf), dtype=leaf["dtype"]).reshape(leaf["shape"])
+                buf, dtype=leaf["dtype"]).reshape(leaf["shape"])
         return out, top["extra"]
 
     def restore_tree(self, step: int, like: Any, check_crc: bool = True) -> Tuple[Any, Dict[str, Any]]:

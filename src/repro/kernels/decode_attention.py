@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .flash_attention import pick_block
+
 NEG_INF = -1e30
 
 
@@ -79,9 +81,7 @@ def flash_decode(
         raise ValueError("query heads must be a multiple of kv heads")
     group = H // KV
     scale_ = D ** -0.5 if scale is None else scale
-    block_k = min(block_k, T)
-    if T % block_k:
-        raise ValueError("cache length must divide block_k")
+    block_k = pick_block(T, block_k)
     nk = T // block_k
 
     kernel = functools.partial(_decode_kernel, scale=scale_, block_k=block_k,

@@ -76,6 +76,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
 
 
+def pick_block(n: int, target: int) -> int:
+    """Largest multiple of 8 that divides ``n`` and is at most ``target``;
+    ``n`` itself when there is none (a block spanning the whole dim always
+    meets the TPU's tiling rule)."""
+    for b in range(min(target, n) // 8 * 8, 0, -8):
+        if n % b == 0:
+            return b
+    return n
+
+
 def flash_attention_fwd(
     q: jax.Array,  # (B, H, S, D)
     k: jax.Array,  # (B, KV, T, D)
@@ -92,10 +102,8 @@ def flash_attention_fwd(
         raise ValueError("query heads must be a multiple of kv heads")
     group = H // KV
     scale_ = D ** -0.5 if scale is None else scale
-    block_q = min(block_q, S)
-    block_k = min(block_k, T)
-    if S % block_q or T % block_k:
-        raise ValueError("sequence lengths must divide block sizes")
+    block_q = pick_block(S, block_q)
+    block_k = pick_block(T, block_k)
     nq, nk = S // block_q, T // block_k
     offs = T - S
 

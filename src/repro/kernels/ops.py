@@ -20,6 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from . import ref
 from .decode_attention import flash_decode
@@ -38,13 +39,41 @@ def _resolve(impl: str) -> str:
     return impl
 
 
+def _per_shard(kernel, q, k, v, *per_row):
+    """Run an attention kernel once per shard of the context mesh.
+
+    XLA cannot partition a Mosaic kernel, so under a mesh of more than one
+    device the call goes through ``shard_map``: batch over the non-"model"
+    axes and heads over "model", each only where the size divides (query
+    and kv heads alike, so every shard keeps whole GQA groups).  ``q`` is
+    (B, H, ...), ``k``/``v`` are (B, KV, ...), ``per_row`` arrays are (B,).
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return kernel(q, k, v, *per_row)
+    B, H, KV = q.shape[0], q.shape[1], k.shape[1]
+    batch, n = [], 1
+    for a in mesh.axis_names:
+        if a != "model" and B % (n * mesh.shape[a]) == 0:
+            batch.append(a)
+            n *= mesh.shape[a]
+    b = tuple(batch) or None
+    m = mesh.shape.get("model", 1)
+    h = "model" if m > 1 and H % m == 0 and KV % m == 0 else None
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(P(b, h), P(b, h), P(b, h)) + (P(b),) * len(per_row),
+        out_specs=P(b, h), check_vma=False)(q, k, v, *per_row)
+
+
 # ---------------------------------------------------------------------------
 # attention
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _attention_pallas(q, k, v, causal, scale, interpret):
-    return flash_attention_fwd(q, k, v, causal=causal, scale=scale,
-                               interpret=interpret)
+    return _per_shard(
+        functools.partial(flash_attention_fwd, causal=causal, scale=scale,
+                          interpret=interpret), q, k, v)
 
 
 def _attention_pallas_fwd(q, k, v, causal, scale, interpret):
@@ -87,11 +116,10 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     impl = _resolve(impl)
     if impl in ("naive", "ref"):
         return ref.decode_attention_naive(q, k, v, length, scale)
-    if impl == "pallas":
-        return flash_decode(q, k, v, length, scale, block_k=block_k)
-    if impl == "interpret":
-        return flash_decode(q, k, v, length, scale, block_k=block_k,
-                            interpret=True)
+    if impl in ("pallas", "interpret"):
+        kernel = functools.partial(flash_decode, scale=scale, block_k=block_k,
+                                   interpret=impl == "interpret")
+        return _per_shard(kernel, q, k, v, length)
     raise ValueError(f"unknown impl {impl!r}")
 
 

@@ -116,8 +116,13 @@ def attention_blockwise(
             hi = jnp.minimum(hi, nk)
         else:
             hi = nk
+        # checkpointed step: the VJP keeps only the (acc, m, l) carry per
+        # kv block and recomputes the (bq, bk) scores, as flash attention's
+        # backward does; storing them holds all S x T probabilities in f32
+        # at once, which alone overflows a 16 GB chip at seq 2048, batch 8
+        step = jax.checkpoint(kv_step)
         (acc, m, l), _ = jax.lax.scan(
-            lambda c, ki: jax.lax.cond(ki < hi, lambda: kv_step(c, ki),
+            lambda c, ki: jax.lax.cond(ki < hi, lambda: step(c, ki),
                                        lambda: (c, None)),
             (acc0, m0, l0), jnp.arange(nk))
         return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)

@@ -43,7 +43,7 @@ from repro.analysis.hlo import analyze_hlo
 from repro.analysis.roofline import model_flops
 from repro.configs import ARCH_IDS, SHAPES, SKIP_CELLS, get_config, resolve
 from repro.launch import sharding as shd
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import decode_specs, input_specs
 from repro.launch.steps import (make_decode_step, make_prefill_step,
                                 make_train_step, train_state_shape)
@@ -125,7 +125,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     set_sharding_profile(profile)
     _last_profile[0] = profile
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_cfg = AdamWConfig(**(opt_overrides or {}))
             state_sds = train_state_shape(model, opt_cfg)

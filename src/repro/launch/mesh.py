@@ -11,39 +11,27 @@ state (device count is locked at first backend init).
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    """axis_types=(Auto,)*n on jax >= 0.5; older jax has neither the enum
-    nor the kwarg, and Auto is its only behaviour anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+def _auto(n: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
-def make_host_mesh():
-    """Whatever this host actually has — smoke tests and examples."""
-    n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"), **_axis_type_kwargs(2))
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where available; on older jax the Mesh object
-    is itself the context manager (equivalent for Auto-typed axes)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+def make_host_mesh(devices: Optional[Sequence] = None):
+    """A data-parallel mesh over ``devices`` (default: every device this
+    host has) — the trainer's mesh on one chip or on a four-chip host."""
+    devices = list(jax.devices() if devices is None else devices)
+    return jax.make_mesh((len(devices), 1), ("data", "model"),
+                         axis_types=_auto(2), devices=devices)
 
 
 def batch_axes(mesh) -> Tuple[str, ...]:

@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.launch.mesh import make_host_mesh, mesh_context
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_generate_loop
 from repro.models import build_model
 
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
@@ -42,7 +44,7 @@ def main() -> None:
 
     gen = make_generate_loop(model, args.gen)
     max_len = args.prompt_len + args.gen + 1
-    with mesh_context(make_host_mesh()):
+    with jax.set_mesh(make_host_mesh()):
         jitted = jax.jit(gen, static_argnums=(2,))
         t0 = time.perf_counter()
         toks = jitted(params, batch, max_len)

@@ -229,11 +229,8 @@ def constrain_dims(x: jax.Array, assignments: Dict[int, str]) -> jax.Array:
     shardings by replicating the tensor-parallel dim — measured as a 16x
     per-device FLOP inflation in the dry-run (EXPERIMENTS.md §Perf it. 2).
     """
-    get_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_mesh is None:  # jax < 0.5: no abstract-mesh context, nothing to pin
-        return x
-    mesh = get_mesh()
-    if mesh is None or getattr(mesh, "empty", True):
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     from jax.sharding import PartitionSpec
 

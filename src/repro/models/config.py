@@ -112,7 +112,10 @@ class ModelConfig:
     # outputs (no dot recompute in bwd — higher useful-FLOP ratio
     # when HBM allows, see §Perf)
     remat_policy: str = "nothing"
-    attn_impl: str = "ref"           # kernels/ops impl selector
+    # kernels/ops impl selectors: "auto" runs the Pallas attention kernel
+    # on a TPU and the XLA reference elsewhere; the scan kernels stay on
+    # "ref" until they compile for the chip at published widths
+    attn_impl: str = "auto"
     scan_impl: str = "ref"
     # "fsdp": model axis = extra data/param shards (best for small-to-mid
     # models at large batch); "tp": Megatron activation sharding on the

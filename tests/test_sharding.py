@@ -6,11 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-try:
-    from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
-except ImportError:  # AxisType landed in jax 0.5; skip on older toolchains
-    pytest.skip("jax.sharding.AxisType unavailable in this jax version",
-                allow_module_level=True)
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, SHAPES, get_config
 from repro.launch import sharding as shd

@@ -76,3 +76,39 @@ def test_emergency_checkpoint_on_crash():
     # and it restores
     out = tr.ckpt.restore_latest()
     assert out is not None
+
+
+def test_failed_emergency_save_raises_checkpoint_error():
+    from repro.checkpoint import CheckpointError
+
+    _, tr = setup(steps=50, ckpt_every=100, root="/ck")
+    orig_load = tr.loader.load
+
+    def exploding_load(e, s):
+        if s >= 3:
+            raise RuntimeError("node failure!")
+        return orig_load(e, s)
+
+    def failing_save(*a, **kw):
+        raise OSError("disk gone")
+
+    tr.loader.load = exploding_load
+    tr.ckpt.save = failing_save
+    with pytest.raises(CheckpointError, match="emergency save at step 3") as ei:
+        tr.fit()
+    # the node failure that triggered the save is kept as the context
+    assert "node failure" in repr(ei.value.__context__.__context__)
+    assert tr.summary()["emergency_step"] is None
+
+
+def test_resume_reports_restored_step_and_does_not_resave():
+    dev, tr = setup(steps=6, ckpt_every=3, root="/ck")
+    out = tr.fit()
+    assert out["restored_step"] is None and out["final_step"] == 6
+    assert out["ckpt_saves"] == 2  # steps 3 and 6; the final save is step 6's
+    _, tr2 = setup(steps=6, ckpt_every=3, root="/ck", dev=dev)
+    back = tr2.fit()
+    assert back["restored_step"] == 6 and back["losses"] == []
+    assert back["ckpt_saves"] == 0
+    for a, b in zip(jax.tree.leaves(out["state"]), jax.tree.leaves(back["state"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
