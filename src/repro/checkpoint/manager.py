@@ -38,6 +38,7 @@ from repro.core.device import Device, ShardedDevice
 from repro.core.graph import ForeactionGraph, FromNode, GraphBuilder
 from repro.core.patterns import register_patterns
 from repro.core.syscalls import Sys
+from repro.spans import span
 from repro.store.staging import STAGE_TAG
 
 from .policy import CheckpointPolicy, SaveInfo, chain_of
@@ -113,21 +114,23 @@ class _LazyBlobs:
     def __getitem__(self, i: int):
         b = self._blobs.get(i)
         if b is None:
-            a = self.arrays[i]
-            lease = (self.pool.lease(a.nbytes, alignment=self.alignment)
-                     if self.pool is not None else None)
-            if lease is not None:
-                mv = lease.mv[: a.nbytes]
-                try:
-                    mv[:] = memoryview(np.ascontiguousarray(a)).cast("B")
-                except (TypeError, ValueError):
-                    mv[:] = a.tobytes()
-                lease.filled(a.nbytes)
-                self._leases.append(lease)
-                b = self._blobs[i] = mv
-            else:
-                b = self._blobs[i] = a.tobytes()
+            with span("ckpt.serialize", leaf=i, bytes=self.arrays[i].nbytes):
+                b = self._blobs[i] = self._serialize(self.arrays[i])
         return b
+
+    def _serialize(self, a: np.ndarray):
+        lease = (self.pool.lease(a.nbytes, alignment=self.alignment)
+                 if self.pool is not None else None)
+        if lease is None:
+            return a.tobytes()
+        mv = lease.mv[: a.nbytes]
+        try:
+            mv[:] = memoryview(np.ascontiguousarray(a)).cast("B")
+        except (TypeError, ValueError):
+            mv[:] = a.tobytes()
+        lease.filled(a.nbytes)
+        self._leases.append(lease)
+        return mv
 
     def release(self) -> None:
         """Return the leased slabs to the pool.  Must run only after every
@@ -474,11 +477,15 @@ class CheckpointManager:
         CRCs).  Each save is followed by a policy-driven GC pass
         (:meth:`gc`).
         """
-        leaves_kp, treedef = jax.tree_util.tree_flatten_with_path(tree)
-        names = [_leaf_name(kp) for kp, _ in leaves_kp]
-        arrays = [np.asarray(v) for _, v in leaves_kp]
-        blobs = _LazyBlobs(arrays, pool=self.save_pool,
-                           alignment=_pool_alignment(self.device))
+        with span("ckpt.save", step=step) as sp:
+            self._save(step, tree, extra, delta, sp)
+
+    def _plan_save(self, step: int, names: List[str], arrays: List[np.ndarray],
+                   blobs: "_LazyBlobs", delta: bool,
+                   ) -> Tuple[List[_Extent], List[int], Optional[int],
+                              Optional[List[int]]]:
+        """(extents, shard sizes, delta base step, per-extent CRCs) of one
+        save; the last two are None for a full save."""
         if step in self.committed_steps():
             # re-saving a committed step (e.g. an emergency save landing on
             # the step a periodic save already wrote) must not overwrite it
@@ -491,29 +498,44 @@ class CheckpointManager:
             self._collect(step)
         extents, shard_sizes = _plan_extents([a.nbytes for a in arrays],
                                              self.num_shards, self.chunk_bytes)
-        base_step: Optional[int] = None
-        if delta:
-            base_map = self._delta_base(names, arrays)
-            if base_map is not None:
-                base_step, chain_crcs = base_map
-                dsizes = [0] * self.num_shards
-                changed: List[Tuple[_Extent, int]] = []
-                for e in extents:
-                    crc = zlib.crc32(
-                        blobs[e.leaf][e.leaf_off : e.leaf_off + e.length])
-                    if chain_crcs.get((names[e.leaf], e.leaf_off, e.length)) == crc:
-                        continue
-                    ne = _Extent(e.leaf, e.leaf_off, e.shard,
-                                 dsizes[e.shard], e.length)
-                    dsizes[e.shard] += e.length
-                    changed.append((ne, crc))
-                extents = [e for e, _ in changed]
-                shard_sizes = dsizes
-                ext_crcs: Optional[List[int]] = [c for _, c in changed]
-            else:
-                delta = False
-        if not delta:
-            ext_crcs = None  # full save: extent CRCs computed lazily below
+        base_map = self._delta_base(names, arrays) if delta else None
+        if base_map is None:
+            return extents, shard_sizes, None, None
+        base_step, chain_crcs = base_map
+        dsizes = [0] * self.num_shards
+        changed: List[Tuple[_Extent, int]] = []
+        for e in extents:
+            crc = zlib.crc32(blobs[e.leaf][e.leaf_off : e.leaf_off + e.length])
+            if chain_crcs.get((names[e.leaf], e.leaf_off, e.length)) == crc:
+                continue
+            ne = _Extent(e.leaf, e.leaf_off, e.shard, dsizes[e.shard], e.length)
+            dsizes[e.shard] += e.length
+            changed.append((ne, crc))
+        return [e for e, _ in changed], dsizes, base_step, [c for _, c in changed]
+
+    def _save(self, step: int, tree: Any, extra: Optional[Dict[str, Any]],
+              delta: bool, sp) -> None:
+        with span("ckpt.plan", step=step):
+            leaves_kp, _ = jax.tree_util.tree_flatten_with_path(tree)
+            names = [_leaf_name(kp) for kp, _ in leaves_kp]
+            arrays = [np.asarray(v) for _, v in leaves_kp]
+            blobs = _LazyBlobs(arrays, pool=self.save_pool,
+                               alignment=_pool_alignment(self.device))
+            extents, shard_sizes, base_step, ext_crcs = self._plan_save(
+                step, names, arrays, blobs, delta)
+            # register is an idempotent builder assignment; the built graph
+            # and its compiled plan are cached by name/(graph, depth-mode),
+            # so every save after the first of a given shape costs two dict
+            # probes
+            graph_name = f"ckpt_save_s{self.num_shards}_e{len(extents)}"
+            self.fa.register(
+                graph_name,
+                lambda S=self.num_shards, E=len(extents), n=graph_name:
+                    build_save_graph(S, E, n))
+            self.fa.plan(graph_name)
+        if sp.is_enabled():
+            sp.set_metadata(bytes=sum(a.nbytes for a in arrays),
+                            kind="delta" if base_step is not None else "full")
         d = self.step_dir(step)
         paths = [self._shard_path(step, i) for i in range(self.num_shards)]
         per_shard = [0] * self.num_shards
@@ -535,45 +557,39 @@ class CheckpointManager:
         def manifest_bytes() -> bytes:
             data = manifest_cache.get("data")
             if data is None:
-                crcs = ext_crcs if ext_crcs is not None else [
-                    zlib.crc32(blobs[e.leaf][e.leaf_off : e.leaf_off + e.length])
-                    for e in extents
-                ]
-                manifest = {
-                    "step": step,
-                    "num_shards": self.num_shards,
-                    "shard_sizes": shard_sizes,
-                    "wall_time": wall_time,
-                    "kind": "delta" if base_step is not None else "full",
-                    "base": base_step,
-                    "leaves": [
-                        {
-                            "name": names[i],
-                            "dtype": str(arrays[i].dtype),
-                            "shape": list(arrays[i].shape),
-                            "nbytes": arrays[i].nbytes,
-                            "crc32": zlib.crc32(blobs[i]),
-                        }
-                        for i in range(len(arrays))
-                    ],
-                    "extents": [
-                        [e.leaf, e.leaf_off, e.shard, e.shard_off, e.length, c]
-                        for e, c in zip(extents, crcs)
-                    ],
-                    "extra": extra or {},
-                }
-                data = manifest_cache["data"] = json.dumps(manifest).encode()
+                with span("ckpt.crc", step=step):
+                    data = manifest_cache["data"] = _manifest()
             return data
 
-        # register is an idempotent builder assignment; the built graph and
-        # its compiled plan are cached by name/(graph, depth-mode), so every
-        # save after the first of a given shape costs two dict probes
-        graph_name = f"ckpt_save_s{self.num_shards}_e{len(extents)}"
-        self.fa.register(
-            graph_name,
-            lambda S=self.num_shards, E=len(extents), n=graph_name:
-                build_save_graph(S, E, n))
-        self.fa.plan(graph_name)
+        def _manifest() -> bytes:
+            crcs = ext_crcs if ext_crcs is not None else [
+                zlib.crc32(blobs[e.leaf][e.leaf_off : e.leaf_off + e.length])
+                for e in extents
+            ]
+            manifest = {
+                "step": step,
+                "num_shards": self.num_shards,
+                "shard_sizes": shard_sizes,
+                "wall_time": wall_time,
+                "kind": "delta" if base_step is not None else "full",
+                "base": base_step,
+                "leaves": [
+                    {
+                        "name": names[i],
+                        "dtype": str(arrays[i].dtype),
+                        "shape": list(arrays[i].shape),
+                        "nbytes": arrays[i].nbytes,
+                        "crc32": zlib.crc32(blobs[i]),
+                    }
+                    for i in range(len(arrays))
+                ],
+                "extents": [
+                    [e.leaf, e.leaf_off, e.shard, e.shard_off, e.length, c]
+                    for e, c in zip(extents, crcs)
+                ],
+                "extra": extra or {},
+            }
+            return json.dumps(manifest).encode()
 
         def capture():
             return {
@@ -604,14 +620,16 @@ class CheckpointManager:
             io.fsync(self.device, cf)
             io.close(self.device, cf)
 
-        try:
-            _save_all()
-        finally:
-            # the wrapped session has drained (or rolled back): no worker
-            # still reads the leased slabs, so they recycle now
-            blobs.release()
+        with span("ckpt.write", step=step):
+            try:
+                _save_all()
+            finally:
+                # the wrapped session has drained (or rolled back): no
+                # worker still reads the leased slabs, so they recycle now
+                blobs.release()
         self._wall_floor = wall_time
-        self.gc()
+        with span("ckpt.gc", step=step):
+            self.gc()
 
     def save_async(self, step: int, tree: Any, extra: Optional[Dict[str, Any]] = None,
                    delta: bool = False) -> None:
@@ -621,10 +639,15 @@ class CheckpointManager:
         still running it is joined first, and if it failed its error is
         raised *here* — a second call can never silently orphan an
         in-flight save or swallow its failure."""
-        with self._async_lock:
-            self._join_pending_locked()
+        with span("ckpt.save_async", step=step) as sp, self._async_lock:
+            with span("ckpt.join", step=step):
+                self._join_pending_locked()
             # snapshot to host memory synchronously; write in the background
-            tree = jax.tree_util.tree_map(np.asarray, tree)
+            with span("ckpt.snapshot", step=step):
+                tree = jax.tree_util.tree_map(np.asarray, tree)
+            if sp.is_enabled():
+                sp.set_metadata(bytes=sum(
+                    x.nbytes for x in jax.tree_util.tree_leaves(tree)))
 
             def run():
                 try:
@@ -814,7 +837,6 @@ class CheckpointManager:
         def _open_all(paths):
             return [io.open(self.device, p, "r") for p in paths]
 
-        fds = _open_all(paths)
         extents = [_Extent(*e[:5]) for e in m["extents"]]
         # group by owning shard: the round-robin extent plan interleaves
         # shards in manifest order, but within one shard file the extents
@@ -825,26 +847,30 @@ class CheckpointManager:
         # backend; the overlay below follows the same order, so restored
         # bytes are identical either way.
         extents.sort(key=lambda e: (e.shard, e.shard_off))
-        ext_args = [(fds[e.shard], e.length, e.shard_off) for e in extents]
 
         @self.fa.wrap("pread_extents", lambda extents: {"extents": extents})
         def _read_all(extents):
             return [io.pread(self.device, fd, n, off) for fd, n, off in extents]
 
-        chunks = _read_all(ext_args)
-        for fd in fds:
-            io.close(self.device, fd)
+        with span("ckpt.read", step=step,
+                  bytes=sum(e.length for e in extents)):
+            fds = _open_all(paths)
+            chunks = _read_all([(fds[e.shard], e.length, e.shard_off)
+                                for e in extents])
+            for fd in fds:
+                io.close(self.device, fd)
         lnames = [lf["name"] for lf in m["leaves"]]
-        for e, c in zip(extents, chunks):
-            if len(c) != e.length:
-                raise CheckpointError(
-                    f"short read: shard {e.shard} off {e.shard_off}: "
-                    f"{len(c)} != {e.length}")
-            buf = bufs.get(lnames[e.leaf])
-            if buf is None:
-                raise CheckpointError(
-                    f"chain member {step} has unknown leaf {lnames[e.leaf]}")
-            buf[e.leaf_off : e.leaf_off + e.length] = c
+        with span("ckpt.overlay", step=step):
+            for e, c in zip(extents, chunks):
+                if len(c) != e.length:
+                    raise CheckpointError(
+                        f"short read: shard {e.shard} off {e.shard_off}: "
+                        f"{len(c)} != {e.length}")
+                buf = bufs.get(lnames[e.leaf])
+                if buf is None:
+                    raise CheckpointError(
+                        f"chain member {step} has unknown leaf {lnames[e.leaf]}")
+                buf[e.leaf_off : e.leaf_off + e.length] = c
 
     def restore(self, step: int, check_crc: bool = True) -> Tuple[Any, Dict[str, Any]]:
         """Parallel chunked restore -> (flat {name: np.ndarray}, extra).
@@ -855,20 +881,23 @@ class CheckpointManager:
         chained restore is verified byte-identical to what the delta save
         hashed — corruption anywhere in the chain fails the restore (and
         ``restore_latest`` falls back to an older step)."""
-        ms = self._manifest_chain(step)
+        with span("ckpt.discover", step=step):
+            ms = self._manifest_chain(step)
         top = ms[-1]
-        bufs: Dict[str, bytearray] = {
-            leaf["name"]: bytearray(leaf["nbytes"]) for leaf in top["leaves"]}
+        with span("ckpt.overlay", step=step):      # the buffers, zeroed
+            bufs: Dict[str, bytearray] = {
+                leaf["name"]: bytearray(leaf["nbytes"]) for leaf in top["leaves"]}
         for m in ms:
             self._read_step_into(m, bufs)
         out: Dict[str, np.ndarray] = {}
-        for leaf in top["leaves"]:
-            # CRC and view the read buffer in place: no host copy per leaf
-            buf = bufs[leaf["name"]]
-            if check_crc and zlib.crc32(buf) != leaf["crc32"]:
-                raise CheckpointError(f"crc mismatch for leaf {leaf['name']}")
-            out[leaf["name"]] = np.frombuffer(
-                buf, dtype=leaf["dtype"]).reshape(leaf["shape"])
+        with span("ckpt.crc", step=step):
+            for leaf in top["leaves"]:
+                # CRC and view the read buffer in place: no host copy per leaf
+                buf = bufs[leaf["name"]]
+                if check_crc and zlib.crc32(buf) != leaf["crc32"]:
+                    raise CheckpointError(f"crc mismatch for leaf {leaf['name']}")
+                out[leaf["name"]] = np.frombuffer(
+                    buf, dtype=leaf["dtype"]).reshape(leaf["shape"])
         return out, top["extra"]
 
     def restore_tree(self, step: int, like: Any, check_crc: bool = True) -> Tuple[Any, Dict[str, Any]]:
@@ -891,17 +920,25 @@ class CheckpointManager:
     def restore_latest(self, like: Any = None) -> Optional[Tuple[int, Any, Dict[str, Any]]]:
         """Newest committed checkpoint that validates; falls back past
         corrupt ones (node-failure recovery path)."""
-        for step in reversed(self.committed_steps()):
-            try:
-                if not self.validate(step):
+        with span("ckpt.restore") as sp:
+            with span("ckpt.discover"):
+                steps = self.committed_steps()
+            for step in reversed(steps):
+                try:
+                    with span("ckpt.discover", step=step):
+                        valid = self.validate(step)
+                    if not valid:
+                        continue
+                    if like is None:
+                        tree, extra = self.restore(step)
+                    else:
+                        tree, extra = self.restore_tree(step, like)
+                except (CheckpointError, FileNotFoundError):
                     continue
-                if like is None:
-                    tree, extra = self.restore(step)
-                else:
-                    tree, extra = self.restore_tree(step, like)
+                if sp.is_enabled():
+                    sp.set_metadata(step=step, bytes=sum(
+                        x.nbytes for x in jax.tree_util.tree_leaves(tree)))
                 return step, tree, extra
-            except (CheckpointError, FileNotFoundError):
-                continue
         return None
 
     # -- replication ---------------------------------------------------------------

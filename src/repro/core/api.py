@@ -36,6 +36,8 @@ import functools
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
+from repro.spans import span
+
 from .backends import (Backend, SharedBackend, SlotScheduler, SyncBackend,
                        make_backend, resolve_priority)
 from .device import Device, OSDevice
@@ -419,22 +421,36 @@ class Foreactor:
         def _tenant_of(args, kwargs) -> Optional[str]:
             return tenant(*args, **kwargs) if callable(tenant) else tenant
 
+        def _speculate(fn, args, kwargs):
+            """``fn`` under one session, inside an ``fa.session`` span that
+            carries the session's counters while a profiler records."""
+            with span("fa.session", graph=graph_name) as sp:
+                ctx = capture(*args, **kwargs)
+                sess = self.activate(graph_name, ctx,
+                                     tenant=_tenant_of(args, kwargs),
+                                     weight=weight, priority=priority)
+                try:
+                    return fn(*args, **kwargs)
+                except BaseException:
+                    # the staging transaction must roll back, not commit
+                    sess.mark_failed()
+                    raise
+                finally:
+                    st = self.deactivate(sess)
+                    if sp.is_enabled():
+                        sp.set_metadata(
+                            intercepted=st.intercepted,
+                            served_async=st.served_async,
+                            pre_issued=st.pre_issued,
+                            wait_s=st.wait_seconds, sync_s=st.sync_seconds,
+                            peek_s=st.peek_seconds,
+                            harvest_s=st.harvest_seconds)
+
         def deco(fn: Callable) -> Callable:
             if not auto_graph:
                 @functools.wraps(fn)
                 def wrapper(*args, **kwargs):
-                    ctx = capture(*args, **kwargs)
-                    sess = self.activate(graph_name, ctx,
-                                         tenant=_tenant_of(args, kwargs),
-                                         weight=weight, priority=priority)
-                    try:
-                        return fn(*args, **kwargs)
-                    except BaseException:
-                        # the staging transaction must roll back, not commit
-                        sess.mark_failed()
-                        raise
-                    finally:
-                        self.deactivate(sess)
+                    return _speculate(fn, args, kwargs)
 
                 wrapper.__foreactor_graph__ = graph_name  # type: ignore[attr-defined]
                 return wrapper
@@ -447,17 +463,7 @@ class Foreactor:
                 with state_lock:
                     mode = state["state"]
                 if mode == "speculating":
-                    ctx = capture(*args, **kwargs)
-                    sess = self.activate(graph_name, ctx,
-                                         tenant=_tenant_of(args, kwargs),
-                                         weight=weight, priority=priority)
-                    try:
-                        return fn(*args, **kwargs)
-                    except BaseException:
-                        sess.mark_failed()
-                        raise
-                    finally:
-                        self.deactivate(sess)
+                    return _speculate(fn, args, kwargs)
                 if mode == "disabled":
                     return fn(*args, **kwargs)
                 # observing: record one more trace, then try to mine

@@ -20,7 +20,6 @@ docs/GLOSSARY.md.
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Deque, Dict, Iterator, List, Optional, Tuple
@@ -43,7 +42,6 @@ class TraceEvent:
     args: Tuple[Any, ...]
     result: Any = None
     error: Optional[BaseException] = None
-    t_seconds: float = 0.0  # service time of this call (serial, by design)
 
     def kind(self) -> Sys:
         return self.sc
@@ -55,7 +53,6 @@ class Trace:
     def __init__(self, name: str = "trace"):
         self.name = name
         self.events: List[TraceEvent] = []
-        self.wall_seconds: float = 0.0
 
     def append(self, ev: TraceEvent) -> None:
         self.events.append(ev)
@@ -164,10 +161,8 @@ class TraceRecorder:
     def __init__(self, device: Device, name: str = "trace"):
         self.device = device
         self.trace = Trace(name)
-        self._t0 = time.perf_counter()
 
     def intercept(self, sc: Sys, args: Tuple[Any, ...]) -> Any:
-        t0 = time.perf_counter()
         ev = TraceEvent(seq=len(self.trace.events), sc=sc, args=args)
         self.trace.append(ev)
         try:
@@ -175,14 +170,11 @@ class TraceRecorder:
             result = execute(self.device, sc, args)
         except BaseException as e:
             ev.error = e
-            ev.t_seconds = time.perf_counter() - t0
             raise
         ev.result = result
-        ev.t_seconds = time.perf_counter() - t0
         return result
 
     def finish(self) -> Trace:
-        self.trace.wall_seconds = time.perf_counter() - self._t0
         return self.trace
 
 

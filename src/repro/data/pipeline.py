@@ -19,6 +19,7 @@ import numpy as np
 from repro.core.api import Foreactor, io
 from repro.core.device import Device
 from repro.core.patterns import register_patterns
+from repro.spans import span
 from repro.store.recordio import HEADER, RecordShardReader, RecordShardWriter
 
 
@@ -146,15 +147,17 @@ class TokenBatchLoader:
     def _read_batch(self, epoch: int, step: int) -> np.ndarray:
         idx = self.batch_indices(epoch, step)
         extents = [self.ds.extent(int(i)) for i in idx]
-
-        if self.prefetch:
-            @self.fa.wrap("pread_extents", lambda extents: {"extents": extents})
-            def _read(extents):
-                return [io.pread(self.ds.device, fd, n, off) for fd, n, off in extents]
-            raw = _read(extents)
-        else:
-            raw = [io.pread(self.ds.device, fd, n, off) for fd, n, off in extents]
-        toks = np.stack([np.frombuffer(r, dtype=self.cfg.dtype) for r in raw])
+        with span("data.read", epoch=epoch, step=step,
+                  bytes=len(extents) * self.ds.record_bytes):
+            if self.prefetch:
+                @self.fa.wrap("pread_extents", lambda extents: {"extents": extents})
+                def _read(extents):
+                    return [io.pread(self.ds.device, fd, n, off)
+                            for fd, n, off in extents]
+                raw = _read(extents)
+            else:
+                raw = [io.pread(self.ds.device, fd, n, off) for fd, n, off in extents]
+            toks = np.stack([np.frombuffer(r, dtype=self.cfg.dtype) for r in raw])
         return toks.astype(np.int32)
 
     def load(self, epoch: int, step: int) -> Dict[str, np.ndarray]:
@@ -163,24 +166,26 @@ class TokenBatchLoader:
         If the background double-buffer already holds this batch, it is
         returned immediately and the next batch starts loading.
         """
-        rec = None
-        if self._bg_pending:
-            self._bg_done.wait()
-            self._bg_pending = False
-            if self._bg_out is not None and self._bg_out[0] == (epoch, step):
-                rec = self._bg_out[1]
-            self._bg_out = None
-        if rec is None:
-            rec = self._read_batch(epoch, step)
-        if self.prefetch:
-            ns, ne = step + 1, epoch
-            if ns >= self.steps_per_epoch:
-                ns, ne = 0, epoch + 1
-            self._ensure_worker()
-            self._bg_done.clear()
-            self._bg_pending = True
-            self._bg_req.put((ne, ns))
-        return {"tokens": rec[:, :-1], "labels": rec[:, 1:]}
+        with span("data.load", epoch=epoch, step=step):
+            rec = None
+            if self._bg_pending:
+                with span("data.wait", epoch=epoch, step=step):
+                    self._bg_done.wait()
+                self._bg_pending = False
+                if self._bg_out is not None and self._bg_out[0] == (epoch, step):
+                    rec = self._bg_out[1]
+                self._bg_out = None
+            if rec is None:
+                rec = self._read_batch(epoch, step)
+            if self.prefetch:
+                ns, ne = step + 1, epoch
+                if ns >= self.steps_per_epoch:
+                    ns, ne = 0, epoch + 1
+                self._ensure_worker()
+                self._bg_done.clear()
+                self._bg_pending = True
+                self._bg_req.put((ne, ns))
+            return {"tokens": rec[:, :-1], "labels": rec[:, 1:]}
 
     def _ensure_worker(self) -> None:
         if self._bg is not None:

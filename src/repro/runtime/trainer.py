@@ -42,6 +42,7 @@ from repro.launch import sharding as shd
 from repro.launch.steps import make_train_step, make_train_state
 from repro.models.api import Model
 from repro.optim.adamw import AdamWConfig
+from repro.spans import span, step_span
 
 
 @dataclass
@@ -143,12 +144,19 @@ class Trainer:
         start_epoch, start_step = 0, 0
         if self.ckpt is not None and self.tcfg.restore:
             t0 = time.perf_counter()
-            out = self.ckpt.restore_latest(like=like)
+            with span("trainer.restore") as sp:
+                out = self.ckpt.restore_latest(like=like)
+                if out is not None:
+                    ckpt_step, tree, extra = out
+                    with span("trainer.place", step=ckpt_step):
+                        # host arrays straight onto their shardings: no copy
+                        # lands whole on one device first
+                        state = jax.block_until_ready(
+                            jax.device_put(tree, state_sh))
+                    if sp.is_enabled():
+                        sp.set_metadata(step=ckpt_step, bytes=sum(
+                            x.nbytes for x in jax.tree.leaves(tree)))
             if out is not None:
-                ckpt_step, tree, extra = out
-                # host arrays straight onto their shardings: no copy lands
-                # whole on one device first
-                state = jax.device_put(tree, state_sh)
                 self.restored_step = ckpt_step
                 self.restore_s = time.perf_counter() - t0
                 start_epoch = int(extra.get("epoch", 0))
@@ -194,6 +202,19 @@ class Trainer:
             if len(self.events) > 1 else None,
         }
 
+    def _periodic_save(self, step: int, state: Any) -> None:
+        extra = {"epoch": step // self.loader.steps_per_epoch, "step": step}
+        t0 = time.perf_counter()
+        delta = self._next_delta()
+        if self.tcfg.write_behind:
+            # blocks only while a previous save is still in flight; the
+            # write graph runs behind compute
+            self.ckpt.save_async(step, state, extra=extra, delta=delta)
+        else:
+            self.ckpt.save(step, state, extra=extra, delta=delta)
+        self.ckpt_wait_s += time.perf_counter() - t0
+        self.ckpt_saves += 1
+
     # -- the loop ------------------------------------------------------------
     def fit(self) -> Dict[str, Any]:
         if self.ckpt is not None and self.tcfg.retention is not None:
@@ -207,45 +228,38 @@ class Trainer:
             saved_step = None
             try:
                 while global_step < self.tcfg.steps:
-                    e, s = divmod(global_step, spe)
-                    batch = self.loader.load(e, s)
-                    if self.batch_extras is not None:
-                        batch = self.batch_extras(batch)
-                    t0 = time.perf_counter()
-                    state, metrics = step_fn(state, self._place_batch(batch))
-                    jax.block_until_ready((state, metrics))
-                    dt = time.perf_counter() - t0
-                    loss = float(metrics["loss"])
-                    if self.first_step_at is None:
-                        self.first_step_at = time.perf_counter()
-                    straggler = ema is not None and dt > self.tcfg.straggler_factor * ema
-                    ema = dt if ema is None else 0.9 * ema + 0.1 * dt
-                    self.events.append(StepEvent(global_step, dt, loss, straggler))
-                    if straggler:
-                        self.stragglers.append(global_step)
-                        print(f"[trainer] STRAGGLER step {global_step}: "
-                              f"{dt:.3f}s vs ema {ema:.3f}s")
-                    if self.tcfg.log_every and global_step % self.tcfg.log_every == 0:
-                        print(f"[trainer] step {global_step:5d} loss {loss:.4f} "
-                              f"gnorm {float(metrics['grad_norm']):.3f} {dt*1e3:.0f}ms")
-                    global_step = self.step = global_step + 1
-                    if self.ckpt is not None and self.tcfg.ckpt_every \
-                            and global_step % self.tcfg.ckpt_every == 0:
-                        e2, s2 = divmod(global_step, spe)
-                        extra = {"epoch": e2, "step": global_step}
+                    with step_span("trainer.step", global_step):
+                        e, s = divmod(global_step, spe)
+                        with span("trainer.load", step=global_step):
+                            batch = self.loader.load(e, s)
+                        if self.batch_extras is not None:
+                            batch = self.batch_extras(batch)
                         t0 = time.perf_counter()
-                        delta = self._next_delta()
-                        if self.tcfg.write_behind:
-                            # blocks only while a previous save is still in
-                            # flight; the write graph runs behind compute
-                            self.ckpt.save_async(global_step, state,
-                                                 extra=extra, delta=delta)
-                        else:
-                            self.ckpt.save(global_step, state, extra=extra,
-                                           delta=delta)
-                        self.ckpt_wait_s += time.perf_counter() - t0
-                        self.ckpt_saves += 1
-                        saved_step = global_step
+                        with span("trainer.put", step=global_step):
+                            batch = self._place_batch(batch)
+                        with span("trainer.compute", step=global_step):
+                            state, metrics = step_fn(state, batch)
+                            jax.block_until_ready((state, metrics))
+                        dt = time.perf_counter() - t0
+                        loss = float(metrics["loss"])
+                        if self.first_step_at is None:
+                            self.first_step_at = time.perf_counter()
+                        straggler = ema is not None and dt > self.tcfg.straggler_factor * ema
+                        ema = dt if ema is None else 0.9 * ema + 0.1 * dt
+                        self.events.append(StepEvent(global_step, dt, loss, straggler))
+                        if straggler:
+                            self.stragglers.append(global_step)
+                            print(f"[trainer] STRAGGLER step {global_step}: "
+                                  f"{dt:.3f}s vs ema {ema:.3f}s")
+                        if self.tcfg.log_every and global_step % self.tcfg.log_every == 0:
+                            print(f"[trainer] step {global_step:5d} loss {loss:.4f} "
+                                  f"gnorm {float(metrics['grad_norm']):.3f} {dt*1e3:.0f}ms")
+                        global_step = self.step = global_step + 1
+                        if self.ckpt is not None and self.tcfg.ckpt_every \
+                                and global_step % self.tcfg.ckpt_every == 0:
+                            with span("trainer.save", step=global_step):
+                                self._periodic_save(global_step, state)
+                            saved_step = global_step
             except BaseException:
                 if self.ckpt is not None:
                     self._emergency_save(global_step, epoch, state)

@@ -1,0 +1,13 @@
+"""Checkpoint: per resume, the per-leaf CRC check of the restored bytes
+(``ckpt.crc`` under each ``ckpt.restore``)."""
+
+from bench import program_spans as ps
+
+
+def read(ctx):
+    spans = ps.window_spans(ctx)
+    restores = ps.named(spans, "ckpt.restore")
+    if not restores:
+        return None
+    return sum(c.dur for r in restores
+               for c in ps.within(spans, r, "ckpt.crc")) / len(restores)
