@@ -93,7 +93,13 @@ _attention_pallas.defvjp(_attention_pallas_fwd, _attention_pallas_bwd)
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
               scale: Optional[float] = None, impl: str = "auto",
               block_q: int = 512, block_k: int = 1024) -> jax.Array:
-    """(B,H,S,D) x (B,KV,T,D)^2 -> (B,H,S,D); GQA via head groups."""
+    """(B,H,S,D) x (B,KV,T,D)^2 -> (B,H,S,D); GQA via head groups.
+
+    ``block_q`` / ``block_k`` reach only ``impl="ref"``, the blockwise XLA
+    twin.  The Pallas kernel (``"pallas"``, ``"interpret"``) plans its own
+    blocks from the shapes (``flash_attention.plan_blocks``), and
+    ``"naive"`` has none.
+    """
     impl = _resolve(impl)
     if impl == "naive":
         return ref.attention_naive(q, k, v, causal, scale)

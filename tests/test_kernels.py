@@ -9,7 +9,8 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.decode_attention import flash_decode
-from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.flash_attention import (flash_attention_fwd, pick_block,
+                                           plan_blocks)
 from repro.kernels.mamba2_scan import mamba2_scan
 from repro.kernels.rwkv6_scan import rwkv6_scan
 
@@ -21,21 +22,72 @@ def rnd(shape, dtype=jnp.float32, scale=1.0):
 
 
 # -- flash attention ------------------------------------------------------------
-@pytest.mark.parametrize("B,H,KV,S,T,D,causal", [
-    (1, 4, 4, 128, 128, 64, True),
-    (2, 8, 2, 128, 256, 64, True),     # GQA + cross lengths
-    (1, 2, 1, 256, 256, 128, False),   # MQA, non-causal
-    (1, 4, 2, 128, 128, 256, True),    # gemma-size head_dim
+def _case(B, H, KV, S, T, D, causal, block=64):
+    """A sweep case at explicit ``block`` x ``block`` blocks, or at the
+    kernel's own plan where ``block`` is None (id suffix ``plan``)."""
+    name = f"{B}-{H}-{KV}-{S}-{T}-{D}-{causal}" + ("" if block else "-plan")
+    return pytest.param(B, H, KV, S, T, D, causal, block, id=name)
+
+
+@pytest.mark.parametrize("B,H,KV,S,T,D,causal,block", [
+    _case(1, 4, 4, 128, 128, 64, True),
+    _case(2, 8, 2, 128, 256, 64, True),     # GQA + cross lengths
+    _case(1, 2, 1, 256, 256, 128, False),   # MQA, non-causal
+    _case(1, 4, 2, 128, 128, 256, True),    # gemma-size head_dim
+    _case(1, 6, 2, 1024, 1024, 64, True, None),  # GQA group 3, two q blocks
+    _case(1, 8, 1, 1024, 1024, 64, True, None),  # MQA, group 8
+    _case(1, 6, 2, 256, 1024, 64, True, None),   # S < T: offset diagonal
+    _case(1, 4, 2, 200, 200, 64, True, None),    # no multiple-of-128 divisor
+    _case(2, 6, 2, 256, 512, 64, False, None),   # non-causal
+    _case(1, 2, 1, 4096, 4096, 64, True, None),  # two KV blocks: the clamp
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_sweep(B, H, KV, S, T, D, causal, dtype):
+def test_flash_attention_sweep(B, H, KV, S, T, D, causal, block, dtype):
     q, k, v = rnd((B, H, S, D), dtype), rnd((B, KV, T, D), dtype), rnd((B, KV, T, D), dtype)
     o_ref = ref.attention_naive(q, k, v, causal)
-    o_ker = flash_attention_fwd(q, k, v, causal, block_q=64, block_k=64,
+    o_ker = flash_attention_fwd(q, k, v, causal, block_q=block, block_k=block,
                                 interpret=True)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(o_ref, np.float32),
                                np.asarray(o_ker, np.float32), atol=tol, rtol=tol)
+
+
+def _live_share(S, T, bq, bc, causal):
+    """Share of (q block, kv chunk) pairs holding a score the mask admits,
+    counted position by position."""
+    qpos = np.arange(S) + (T - S)
+    kpos = np.arange(T)
+    admit = kpos[None, :] <= qpos[:, None] if causal else np.ones((S, T), bool)
+    return admit.reshape(S // bq, bq, T // bc, bc).any(axis=(1, 3)).mean()
+
+
+@pytest.mark.parametrize("S,T,D,causal", [
+    (2048, 2048, 64, True),     # Granite-3.0 MoE and TinyLlama-1.1B training
+    (512, 2048, 128, True),
+    (4096, 4096, 256, True),
+    (1500, 1500, 64, False),    # Whisper's encoder length
+    (200, 200, 64, True),
+])
+def test_plan_blocks(S, T, D, causal):
+    plan = plan_blocks(S, T, D, causal)
+    assert S % plan.block_q == 0 and T % plan.block_k == 0
+    assert plan.block_k % plan.block_c == 0
+    assert plan.live_share == pytest.approx(
+        _live_share(S, T, plan.block_q, plan.block_c, causal), abs=1e-12)
+    # explicit blocks override the plan (through pick_block's fallback)
+    over = plan_blocks(S, T, D, causal, block_q=8, block_k=8)
+    assert (over.block_q, over.block_k) == (pick_block(S, 8), pick_block(T, 8))
+
+
+def test_plan_blocks_at_2048():
+    """Large blocks at S = T = 2048, D = 64, and a causal live share below
+    one: the dead chunks are the rest."""
+    plan = plan_blocks(2048, 2048, 64, True)
+    assert plan.block_q >= 512 and plan.block_k >= 512
+    assert plan.live_share == _live_share(2048, 2048, plan.block_q, plan.block_c, True)
+    assert plan.live_share < 1.0
+    over = plan_blocks(2048, 2048, 64, True, block_q=256, block_k=128)
+    assert (over.block_q, over.block_k, over.block_c) == (256, 128, 128)
 
 
 def test_blockwise_ref_matches_naive_ragged_lengths():
