@@ -91,7 +91,8 @@ def model_flops(cfg, shape, mode: str) -> float:
             n_active += D * hd * (H + 2 * KV) + H * hd * D
         elif kind == "mla":
             m = cfg.mla
-            n_active += (D * m.q_lora + m.q_lora * cfg.n_heads * (m.qk_nope + m.qk_rope)
+            qk = cfg.n_heads * (m.qk_nope + m.qk_rope)
+            n_active += ((D * m.q_lora + m.q_lora * qk if m.q_lora else D * qk)
                          + D * (m.kv_lora + m.qk_rope)
                          + m.kv_lora * cfg.n_heads * (m.qk_nope + m.v_head)
                          + cfg.n_heads * m.v_head * D)

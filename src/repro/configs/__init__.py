@@ -29,6 +29,7 @@ ARCH_IDS = [
     "whisper_tiny",
     "zamba2_1_2b",
     "rwkv6_7b",
+    "deepseek_v2_lite",
 ]
 
 # public ids as given in the assignment -> module names
@@ -43,6 +44,7 @@ ALIASES = {
     "whisper-tiny": "whisper_tiny",
     "zamba2-1.2b": "zamba2_1_2b",
     "rwkv6-7b": "rwkv6_7b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

@@ -1,7 +1,14 @@
 """DeepSeek-V2 236B — MLA (kv_lora 512) + fine-grained MoE:
-160 routed experts top-6 + 2 shared, first layer dense [arXiv:2405.04434]."""
+160 routed experts top-6 + 2 shared, first layer dense, YaRN rope (factor
+40), raw top-6 gates scaled by 16 [arXiv:2405.04434;
+hf:deepseek-ai/DeepSeek-V2 config.json].
 
-from repro.models.config import MLAConfig, ModelConfig, MoEConfig
+Not modelled: group-limited routing (``n_group`` 8, ``topk_group`` 3: each
+token's experts drawn from its 3 best device groups; routed here over all
+160), the sequence-wise auxiliary loss (the program adds its Switch loss),
+rotary on interleaved pairs (rotated here on halves)."""
+
+from repro.models.config import MLAConfig, ModelConfig, MoEConfig, YarnScaling
 
 
 def full() -> ModelConfig:
@@ -14,8 +21,12 @@ def full() -> ModelConfig:
                       v_head=128),
         moe=MoEConfig(num_experts=160, top_k=6, d_expert=1536, num_shared=2,
                       first_dense_layers=1, dense_d_ff=12288,
-                      capacity_factor=1.0),
+                      capacity_factor=1.0, norm_topk=False, routed_scale=16.0),
         mlp_act="silu", rope_theta=10000.0,
+        rope_scaling=YarnScaling(factor=40.0,
+                                 original_max_position_embeddings=4096,
+                                 beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                                 mscale_all_dim=0.707),
         sharding_profile="tp",
     )
 

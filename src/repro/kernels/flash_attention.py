@@ -12,7 +12,8 @@ the BlockSpec index maps: the KV block for query head ``h`` is head
 QKᵀ takes q and k in their input dtype with float32 accumulation; the
 scale is applied to the float32 scores.  m, l and acc are float32 (m and
 l replicated across the 128 lanes), and p is cast to v's dtype for the PV
-product.
+product.  v may have a head size ``Dv`` of its own (MLA: q and k of 192,
+v of 128); ``acc`` and the output take it.
 
 Causal masking skips chunks wholly above the diagonal via ``pl.when`` (no
 MXU work), applies an iota mask only on chunks that straddle it, and
@@ -58,17 +59,20 @@ def pick_block(n: int, target: int) -> int:
 
 def plan_blocks(S: int, T: int, D: int, causal: bool,
                 block_q: Optional[int] = None,
-                block_k: Optional[int] = None) -> BlockPlan:
+                block_k: Optional[int] = None,
+                Dv: Optional[int] = None) -> BlockPlan:
     """Blocks for one call from its shapes: q length ``S``, kv length
-    ``T`` and head size ``D``.
+    ``T``, head size ``D`` and v's head size ``Dv`` (default ``D``).
 
     q blocks of up to 512 rows; KV blocks as long as ``_KV_BLOCK_BYTES``
-    allows (the whole of a 2048-key sequence at D <= 128), so that a head's
-    K and V are fetched once for all its q blocks; chunks of up to 512
-    keys.  ``block_q`` / ``block_k`` override the targets.
+    allows for the wider of K and V (the whole of a 2048-key sequence at
+    head sizes up to 128), so that a head's K and V are fetched once for
+    all its q blocks; chunks of up to 512 keys.  ``block_q`` / ``block_k``
+    override the targets.
     """
+    lanes = -(-max(D, Dv or D) // 128) * 128
     bq = pick_block(S, block_q or 512)
-    bk = pick_block(T, block_k or _KV_BLOCK_BYTES // (2 * 2 * max(D, 128)))
+    bk = pick_block(T, block_k or _KV_BLOCK_BYTES // (2 * 2 * lanes))
     bc = pick_block(bk, 512)
     nq, nc = S // bq, T // bc
     live = nq * nc
@@ -90,7 +94,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                 block_k: int, block_c: int, num_k_blocks: int):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
-    D = q_ref.shape[-1]
+    Dv = v_ref.shape[-1]
 
     @pl.when(ki == 0)
     def _init():
@@ -101,7 +105,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     def _compute(c: int, masked: bool):
         q = q_ref[0, 0]                                 # (bq, D)
         k = k_ref[0, 0, c * block_c:(c + 1) * block_c]  # (bc, D)
-        v = v_ref[0, 0, c * block_c:(c + 1) * block_c]  # (bc, D)
+        v = v_ref[0, 0, c * block_c:(c + 1) * block_c]  # (bc, Dv)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (bq, bc)
@@ -117,7 +121,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         corr = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
         m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * _lanes(corr, D) + jax.lax.dot_general(
+        acc_scr[...] = acc_scr[...] * _lanes(corr, Dv) + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
@@ -137,13 +141,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / _lanes(l, D)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / _lanes(l, Dv)).astype(o_ref.dtype)
 
 
 def flash_attention_fwd(
     q: jax.Array,  # (B, H, S, D)
     k: jax.Array,  # (B, KV, T, D)
-    v: jax.Array,  # (B, KV, T, D)
+    v: jax.Array,  # (B, KV, T, Dv)
     causal: bool = True,
     scale: Optional[float] = None,
     block_q: Optional[int] = None,
@@ -152,11 +156,12 @@ def flash_attention_fwd(
 ) -> jax.Array:
     B, H, S, D = q.shape
     KV, T = k.shape[1], k.shape[2]
+    Dv = v.shape[-1]
     if H % KV:
         raise ValueError("query heads must be a multiple of kv heads")
     group = H // KV
     scale_ = D ** -0.5 if scale is None else scale
-    plan = plan_blocks(S, T, D, causal, block_q, block_k)
+    plan = plan_blocks(S, T, D, causal, block_q, block_k, Dv)
     bq, bk = plan.block_q, plan.block_k
     nq, nk = S // bq, T // bk
     offs = T - S
@@ -175,14 +180,14 @@ def flash_attention_fwd(
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
             pl.BlockSpec((1, 1, bk, D), kv_index),
-            pl.BlockSpec((1, 1, bk, D), kv_index),
+            pl.BlockSpec((1, 1, bk, Dv), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, Dv), lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),

@@ -93,7 +93,8 @@ _attention_pallas.defvjp(_attention_pallas_fwd, _attention_pallas_bwd)
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
               scale: Optional[float] = None, impl: str = "auto",
               block_q: int = 512, block_k: int = 1024) -> jax.Array:
-    """(B,H,S,D) x (B,KV,T,D)^2 -> (B,H,S,D); GQA via head groups.
+    """(B,H,S,D) x (B,KV,T,D) x (B,KV,T,Dv) -> (B,H,S,Dv); GQA via head
+    groups; v may have a head size of its own (MLA).
 
     ``block_q`` / ``block_k`` reach only ``impl="ref"``, the blockwise XLA
     twin.  The Pallas kernel (``"pallas"``, ``"interpret"``) plans its own

@@ -26,7 +26,7 @@ NEG_INF = -1e30
 def attention_naive(
     q: jax.Array,  # (B, H, S, D)
     k: jax.Array,  # (B, KV, T, D)
-    v: jax.Array,  # (B, KV, T, D)
+    v: jax.Array,  # (B, KV, T, Dv)
     causal: bool = True,
     scale: Optional[float] = None,
 ) -> jax.Array:
@@ -52,7 +52,7 @@ def attention_naive(
 def attention_blockwise(
     q: jax.Array,  # (B, H, S, D)
     k: jax.Array,  # (B, KV, T, D)
-    v: jax.Array,  # (B, KV, T, D)
+    v: jax.Array,  # (B, KV, T, Dv)
     causal: bool = True,
     scale: Optional[float] = None,
     block_q: int = 512,
@@ -67,6 +67,7 @@ def attention_blockwise(
     """
     B, H, S, D = q.shape
     KV, T = k.shape[1], k.shape[2]
+    Dv = v.shape[-1]
     G = H // KV
     scale_ = D ** -0.5 if scale is None else scale
 
@@ -82,15 +83,16 @@ def attention_blockwise(
     nk = T // block_k
     offs = T - S
 
-    # (B, KV, nk, bk, D) views
-    kb = k.reshape(B, KV, nk, block_k, D)
-    vb = v.reshape(B, KV, nk, block_k, D)
+    # (nk, B, KV, bk, D): KV blocks as the scan's inputs, so that the VJP
+    # stacks one cotangent slice per block (indexing a closed-over array
+    # instead builds a whole-size cotangent at every step)
+    kb = jnp.moveaxis(k.reshape(B, KV, nk, block_k, D), 2, 0)
+    vb = jnp.moveaxis(v.reshape(B, KV, nk, block_k, Dv), 2, 0)
 
     def q_block(qi, qchunk):  # qchunk: (B, H, bq, D)
-        def kv_step(carry, ki):
+        def kv_step(carry, blk):
             acc, m, l = carry
-            kk = jax.lax.dynamic_index_in_dim(kb, ki, axis=2, keepdims=False)
-            vv = jax.lax.dynamic_index_in_dim(vb, ki, axis=2, keepdims=False)
+            kk, vv, ki = blk
             kk = jnp.repeat(kk, G, axis=1)  # (B, H, bk, D)
             vv = jnp.repeat(vv, G, axis=1)
             s = jnp.einsum("bhqd,bhkd->bhqk", qchunk * scale_, kk).astype(jnp.float32)
@@ -107,24 +109,21 @@ def attention_blockwise(
             ).astype(jnp.float32)
             return (acc, m_new, l_new), None
 
-        acc0 = jnp.zeros((B, H, block_q, D), jnp.float32)
+        acc0 = jnp.zeros((B, H, block_q, Dv), jnp.float32)
         m0 = jnp.full((B, H, block_q), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, H, block_q), jnp.float32)
-        if causal:
-            # skip fully-masked kv blocks for this q block
-            hi = ((qi + 1) * block_q + offs + block_k - 1) // block_k
-            hi = jnp.minimum(hi, nk)
-        else:
-            hi = nk
+        # causal: only the kv blocks this q block sees (qi is static)
+        hi = min(max(((qi + 1) * block_q + offs + block_k - 1) // block_k, 0),
+                 nk) if causal else nk
+        if hi == 0:
+            return jnp.zeros((B, H, block_q, Dv), q.dtype)
         # checkpointed step: the VJP keeps only the (acc, m, l) carry per
         # kv block and recomputes the (bq, bk) scores, as flash attention's
         # backward does; storing them holds all S x T probabilities in f32
         # at once, which alone overflows a 16 GB chip at seq 2048, batch 8
-        step = jax.checkpoint(kv_step)
         (acc, m, l), _ = jax.lax.scan(
-            lambda c, ki: jax.lax.cond(ki < hi, lambda: step(c, ki),
-                                       lambda: (c, None)),
-            (acc0, m0, l0), jnp.arange(nk))
+            jax.checkpoint(kv_step), (acc0, m0, l0),
+            (kb[:hi], vb[:hi], jnp.arange(hi)))
         return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 
     qb = q.reshape(B, H, nq, block_q, D)

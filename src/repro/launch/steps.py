@@ -24,10 +24,11 @@ def train_state_shape(model: Model, opt_cfg: AdamWConfig) -> Dict[str, Any]:
 
 def make_train_step(model: Model, opt_cfg: AdamWConfig):
     def train_step(state: Dict[str, Any], batch: Dict[str, jax.Array]):
-        loss, grads = jax.value_and_grad(model.loss)(state["params"], batch)
+        (loss, counts), grads = jax.value_and_grad(
+            model.loss_and_stats, has_aux=True)(state["params"], batch)
         new_params, new_opt, metrics = adamw_update(
             opt_cfg, state["params"], grads, state["opt"])
-        metrics = dict(metrics, loss=loss)
+        metrics = dict(metrics, loss=loss, **counts)
         return {"params": new_params, "opt": new_opt}, metrics
 
     return train_step

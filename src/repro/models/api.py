@@ -17,6 +17,9 @@ class Model:
     loss: Callable
     prefill: Callable
     decode_step: Callable
+    #: (params, batch) -> (loss, counts): the loss with the step's counters
+    #: (``moe_assigned``, ``moe_kept`` where the model routes experts)
+    loss_and_stats: Callable
     logits: Optional[Callable] = None
 
     @property
@@ -32,6 +35,7 @@ def build_model(cfg: ModelConfig) -> Model:
             loss=lambda params, batch: whisper.loss(cfg, params, batch),
             prefill=lambda params, batch, max_len: whisper.prefill(cfg, params, batch, max_len),
             decode_step=lambda params, cache, token, pos: whisper.decode_step(cfg, params, cache, token, pos),
+            loss_and_stats=lambda params, batch: (whisper.loss(cfg, params, batch), {}),
         )
     return Model(
         cfg=cfg,
@@ -39,5 +43,6 @@ def build_model(cfg: ModelConfig) -> Model:
         loss=lambda params, batch: lm.loss(cfg, params, batch),
         prefill=lambda params, batch, max_len: lm.prefill(cfg, params, batch, max_len),
         decode_step=lambda params, cache, token, pos: lm.decode_step(cfg, params, cache, token, pos),
+        loss_and_stats=lambda params, batch: lm.loss_and_stats(cfg, params, batch),
         logits=lambda params, batch: lm.logits_fn(cfg, params, batch),
     )

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from repro.kernels import ops
 from .common import (apply_mrope, apply_norm, apply_rope, constrain_dims,
                      dense_init, norm_init)
-from .config import ModelConfig
+from .config import ModelConfig, yarn_mscale
 
 
 # ---------------------------------------------------------------------------
@@ -50,8 +50,8 @@ def _qkv(cfg: ModelConfig, p: Dict, x: jax.Array, positions) -> Tuple:
         q = apply_mrope(q, positions, cfg.rope_theta, cfg.mrope_sections)
         k = apply_mrope(k, positions, cfg.rope_theta, cfg.mrope_sections)
     elif cfg.rope_type == "standard":
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     # heads on "model"; if the head count does not divide (28-head qwen,
     # MQA), q falls back to SEQUENCE sharding (context parallelism) and
     # k/v stay replicated over model.  Never shard head_dim: it is the
@@ -129,10 +129,14 @@ def mla_init(cfg: ModelConfig, key) -> Dict:
     dt = cfg.param_jdtype()
     ks = jax.random.split(key, 8)
     qk_head = m.qk_nope + m.qk_rope
+    if m.q_lora:
+        q = {"q_down": dense_init(ks[0], D, (m.q_lora,), dt),
+             "q_norm": norm_init(cfg, m.q_lora),
+             "q_up": dense_init(ks[1], m.q_lora, (H, qk_head), dt)}
+    else:
+        q = {"wq": dense_init(ks[0], D, (H, qk_head), dt)}
     return {
-        "q_down": dense_init(ks[0], D, (m.q_lora,), dt),
-        "q_norm": norm_init(cfg, m.q_lora),
-        "q_up": dense_init(ks[1], m.q_lora, (H, qk_head), dt),
+        **q,
         "kv_down": dense_init(ks[2], D, (m.kv_lora + m.qk_rope,), dt),
         "kv_norm": norm_init(cfg, m.kv_lora),
         "k_up": dense_init(ks[3], m.kv_lora, (H, m.qk_nope), dt),
@@ -141,17 +145,31 @@ def mla_init(cfg: ModelConfig, key) -> Dict:
     }
 
 
+def mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale: (qk head size)^-1/2, times mscale(factor,
+    mscale_all_dim)^2 under YaRN."""
+    m, y = cfg.mla, cfg.rope_scaling
+    scale = (m.qk_nope + m.qk_rope) ** -0.5
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_qkv(cfg: ModelConfig, p: Dict, x: jax.Array, positions):
     m = cfg.mla
-    cq = jnp.einsum("bsd,dl->bsl", x, p["q_down"].astype(x.dtype))
-    cq = apply_norm(cfg, p["q_norm"], cq)
-    q = jnp.einsum("bsl,lhk->bshk", cq, p["q_up"].astype(x.dtype))
+    if m.q_lora:
+        cq = jnp.einsum("bsd,dl->bsl", x, p["q_down"].astype(x.dtype))
+        cq = apply_norm(cfg, p["q_norm"], cq)
+        q = jnp.einsum("bsl,lhk->bshk", cq, p["q_up"].astype(x.dtype))
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
     q_nope, q_pe = q[..., : m.qk_nope], q[..., m.qk_nope:]
     ckv_full = jnp.einsum("bsd,dl->bsl", x, p["kv_down"].astype(x.dtype))
     ckv, k_pe = ckv_full[..., : m.kv_lora], ckv_full[..., m.kv_lora:]
     ckv = apply_norm(cfg, p["kv_norm"], ckv)
-    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
-    k_pe = apply_rope(k_pe[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    q_pe = apply_rope(q_pe, positions, cfg.rope_theta, cfg.rope_scaling)
+    k_pe = apply_rope(k_pe[:, :, None, :], positions, cfg.rope_theta,
+                      cfg.rope_scaling)[:, :, 0]
     q_nope = constrain_dims(q_nope, {0: "dp", 2: "model"})
     q_pe = constrain_dims(q_pe, {0: "dp", 2: "model"})
     return q_nope, q_pe, ckv, k_pe
@@ -169,13 +187,11 @@ def mla_apply(cfg: ModelConfig, p: Dict, x: jax.Array, positions,
     k = jnp.concatenate([k_nope,
                          jnp.broadcast_to(k_pe[:, :, None, :],
                                           k_nope.shape[:3] + (m.qk_rope,))], -1)
-    scale = (m.qk_nope + m.qk_rope) ** -0.5
-    # pad v to qk head dim for the shared kernel, then slice back
+    # v at its own head size (v_head, under the qk head size)
     o = ops.attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                      jnp.pad(v, ((0, 0), (0, 0), (0, 0),
-                                  (0, q.shape[-1] - m.v_head))).transpose(0, 2, 1, 3),
-                      causal=causal, scale=scale, impl=cfg.attn_impl)
-    o = o.transpose(0, 2, 1, 3)[..., : m.v_head]
+                      v.transpose(0, 2, 1, 3), causal=causal,
+                      scale=mla_scale(cfg), impl=cfg.attn_impl)
+    o = o.transpose(0, 2, 1, 3)
     o = constrain_dims(o, {0: "dp", 2: "model"})
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(x.dtype))
 
@@ -206,7 +222,6 @@ def mla_decode(cfg: ModelConfig, p: Dict, x: jax.Array, pos: jax.Array,
     """Latent-space decode: queries are projected INTO the compressed space
     (absorbed k_up) so attention runs against the (kv_lora+rope) cache
     directly — the MLA serving trick."""
-    m = cfg.mla
     B = x.shape[0]
     positions = pos[:, None]
     q_nope, q_pe, ckv_new, kpe_new = _mla_qkv(cfg, p, x, positions)
@@ -216,7 +231,7 @@ def mla_decode(cfg: ModelConfig, p: Dict, x: jax.Array, pos: jax.Array,
         cache["kpe"], kpe_new.astype(cache["kpe"].dtype), pos)
     # absorb k_up into q:   q_lat = q_nope @ k_up^T  -> (B,1,H,kv_lora)
     q_lat = jnp.einsum("bshk,lhk->bshl", q_nope, p["k_up"].astype(x.dtype))
-    scale = (m.qk_nope + m.qk_rope) ** -0.5
+    scale = mla_scale(cfg)
     T = ckv_c.shape[1]
     logits = (jnp.einsum("bhl,btl->bht", q_lat[:, 0], ckv_c)
               + jnp.einsum("bhk,btk->bht", q_pe[:, 0], kpe_c)) * scale

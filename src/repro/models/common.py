@@ -8,7 +8,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .config import ModelConfig
+from .config import ModelConfig, YarnScaling, yarn_mscale
 
 
 # ---------------------------------------------------------------------------
@@ -56,17 +56,48 @@ def apply_norm(cfg: ModelConfig, p: Dict, x: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 # RoPE (standard + multimodal M-RoPE)
 # ---------------------------------------------------------------------------
-def rope_freqs(dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def rope_freqs(dim: int, theta: float,
+               scaling: Optional[YarnScaling] = None) -> jax.Array:
+    """Inverse frequencies of a ``dim``-wide rotary part; with YaRN, the
+    slow ones (past the correction range) are interpolated (divided by
+    ``factor``), the fast ones (before it) kept, and a linear ramp blends
+    the two across it."""
+    extra = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if scaling is None:
+        return extra
+    y = scaling
+
+    def corr_dim(rotations: float) -> float:
+        return (dim * math.log(y.original_max_position_embeddings
+                                / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(y.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(y.beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / y.factor * ramp + extra * (1.0 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def rope_mscale(scaling: Optional[YarnScaling]) -> float:
+    """The factor YaRN puts on cos and sin (1 without scaling)."""
+    if scaling is None:
+        return 1.0
+    return (yarn_mscale(scaling.factor, scaling.mscale)
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling: Optional[YarnScaling] = None) -> jax.Array:
     """x: (B, S, H, D) with D even; positions: (B, S) int."""
     D = x.shape[-1]
-    freqs = rope_freqs(D, theta)  # (D/2,)
+    freqs = rope_freqs(D, theta, scaling)  # (D/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B,S,D/2)
+    ms = rope_mscale(scaling)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if ms != 1.0:
+        cos, sin = cos * ms, sin * ms
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -2,8 +2,9 @@
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Optional, Tuple
+import math
+from dataclasses import dataclass
+from typing import Any, Mapping, Optional, Tuple
 
 
 @dataclass(frozen=True)
@@ -29,15 +30,55 @@ class MoEConfig:
                                   # smoke/tests — the XLA fallback lowers to
                                   # dense per-expert dots, so big shapes use
                                   # the capacity path)
+    # this chip's share under expert parallelism: routed experts
+    # [expert_offset, expert_offset + experts_held) live here (0: all).  The
+    # router keeps all num_experts outputs; assignments to experts held
+    # elsewhere add nothing here.
+    experts_held: int = 0
+    expert_offset: int = 0
+    norm_topk: bool = True        # renormalise the top-k gates to sum to 1
+    routed_scale: float = 1.0     # routed output multiplier (DeepSeek's
+                                  # routed_scaling_factor)
+
+    def __post_init__(self):
+        if not 0 <= self.expert_offset < self.expert_offset + self.held \
+                <= self.num_experts:
+            raise ValueError(
+                f"held experts [{self.expert_offset}, "
+                f"{self.expert_offset + self.held}) outside the "
+                f"{self.num_experts} the router covers")
+
+    @property
+    def held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.num_experts
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    q_lora: int = 1536
+    q_lora: int = 1536            # 0: no query compression (a direct wq)
     kv_lora: int = 512
     qk_nope: int = 128
     qk_rope: int = 64
     v_head: int = 128
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 configures it:
+    frequencies blended between interpolated and extrapolated over the
+    correction range that ``beta_fast``/``beta_slow`` give, cos and sin
+    scaled by mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 @dataclass(frozen=True)
@@ -95,6 +136,7 @@ class ModelConfig:
     norm_unit_offset: bool = False   # RMSNorm computes (1 + w) * x_hat (Gemma)
     rope_theta: float = 10000.0
     rope_type: str = "standard"      # "standard" | "mrope" | "none"
+    rope_scaling: Optional[YarnScaling] = None
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)
     logit_softcap: float = 0.0
     moe: Optional[MoEConfig] = None
@@ -123,6 +165,17 @@ class ModelConfig:
     # DeepSeek-V2's 160-expert layers where EP is mandatory).
     sharding_profile: str = "fsdp"
 
+    def __post_init__(self):
+        # nested groups may come as mappings (a JSON configuration)
+        for name, cls in _NESTED.items():
+            v = getattr(self, name)
+            if isinstance(v, Mapping):
+                object.__setattr__(self, name, _from_mapping(cls, v))
+        for name in ("block_pattern", "mrope_sections"):
+            v = getattr(self, name)
+            if not isinstance(v, tuple):
+                object.__setattr__(self, name, tuple(v))
+
     @property
     def hd(self) -> int:
         return self.head_dim if self.head_dim else self.d_model // self.n_heads
@@ -149,3 +202,15 @@ class ModelConfig:
         import jax.numpy as jnp
 
         return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[self.compute_dtype]
+
+
+_NESTED = {"moe": MoEConfig, "mla": MLAConfig, "mamba": MambaConfig,
+           "rwkv": RWKVConfig, "enc_dec": EncDecConfig,
+           "rope_scaling": YarnScaling}
+
+
+def _from_mapping(cls, m: Mapping[str, Any]):
+    kw = dict(m)
+    if cls is YarnScaling and kw.pop("type", "yarn") != "yarn":
+        raise ValueError(f"rope_scaling {m!r}: only yarn is modelled")
+    return cls(**kw)

@@ -135,10 +135,18 @@ def init(cfg: ModelConfig, rng) -> Dict:
 # ---------------------------------------------------------------------------
 # forward (training)
 # ---------------------------------------------------------------------------
+def _no_stats() -> Dict[str, jax.Array]:
+    """A layer's stats where it routes nothing: the auxiliary loss and the
+    MoE assignment counts (``mlp.moe_apply``), all zero."""
+    return {"aux": jnp.zeros((), jnp.float32),
+            "moe_assigned": jnp.zeros((), jnp.int32),
+            "moe_kept": jnp.zeros((), jnp.int32)}
+
+
 def _apply_ffn(cfg: ModelConfig, ffn_kind: str, fp: Dict, h, serve=False):
     if ffn_kind == "moe":
         return mlpm.moe_apply(cfg, fp, h, serve=serve)
-    return mlpm.mlp_apply(cfg, fp, h), jnp.zeros((), jnp.float32)
+    return mlpm.mlp_apply(cfg, fp, h), _no_stats()
 
 
 def _apply_attn_layer(cfg: ModelConfig, kind: str, ffn_kind: str, lp: Dict,
@@ -147,16 +155,16 @@ def _apply_attn_layer(cfg: ModelConfig, kind: str, ffn_kind: str, lp: Dict,
     h = apply_norm(cfg, lp["ln1"], x)
     a = attn_fn(cfg, lp["attn"], h, positions)
     if cfg.parallel_block:
-        f, aux = _apply_ffn(cfg, ffn_kind, lp["ffn"], h, serve)
-        return x + a + f, aux
+        f, stats = _apply_ffn(cfg, ffn_kind, lp["ffn"], h, serve)
+        return x + a + f, stats
     # pin the residual to batch-only sharding at the psum point: without
     # this GSPMD keeps x d_model-sharded and re-gathers it (in f32) for
     # every consumer — ~3 redundant (B,S,D) all-gathers per layer on the
     # tp profile (EXPERIMENTS §Perf it. 12).
     x = constrain_batch(x + a)
     h = apply_norm(cfg, lp["ln2"], x)
-    f, aux = _apply_ffn(cfg, ffn_kind, lp["ffn"], h, serve)
-    return x + f, aux
+    f, stats = _apply_ffn(cfg, ffn_kind, lp["ffn"], h, serve)
+    return x + f, stats
 
 
 def _apply_layer(cfg: ModelConfig, g: LayerGroup, lp: Dict, x, positions,
@@ -167,26 +175,31 @@ def _apply_layer(cfg: ModelConfig, g: LayerGroup, lp: Dict, x, positions,
         return _apply_attn_layer(cfg, "attn", "mlp", shared, x, positions)
     if g.kind == "mamba2":
         h = apply_norm(cfg, lp["ln1"], x)
-        return x + ssm.mamba2_apply(cfg, lp["mixer"], h), jnp.zeros((), jnp.float32)
+        return x + ssm.mamba2_apply(cfg, lp["mixer"], h), _no_stats()
     if g.kind == "rwkv6":
         h = apply_norm(cfg, lp["ln1"], x)
         tm, _ = ssm.rwkv6_time_mix(cfg, lp["tm"], h)
         x = x + tm
         h = apply_norm(cfg, lp["ln2"], x)
         cm, _ = ssm.rwkv6_channel_mix(cfg, lp["tm"], h)
-        return x + cm, jnp.zeros((), jnp.float32)
+        return x + cm, _no_stats()
     raise ValueError(g.kind)
 
 
-def backbone(cfg: ModelConfig, params: Dict, batch: Dict) -> Tuple[jax.Array, jax.Array]:
-    """tokens -> final hidden states (B,S,D) + total aux loss."""
+def backbone(cfg: ModelConfig, params: Dict, batch: Dict
+             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """tokens -> final hidden states (B,S,D) + the layers' stats summed
+    (``aux``: the total auxiliary loss; the MoE assignment counts)."""
     x = embed_tokens(cfg, params["embed"], batch["tokens"])
     x = merge_visual(cfg, x, batch)
     x = constrain_batch(x)
     if cfg.rwkv is not None:
         x = apply_norm(cfg, params["ln0"], x)
     positions = positions_for(cfg, batch)
-    aux_total = jnp.zeros((), jnp.float32)
+    total = _no_stats()
+
+    def add(a, b):
+        return jax.tree.map(jnp.add, a, b)
 
     for gi, g in enumerate(layer_groups(cfg)):
         gp = params["layers"][gi]
@@ -195,32 +208,40 @@ def backbone(cfg: ModelConfig, params: Dict, batch: Dict) -> Tuple[jax.Array, ja
                 return _apply_layer(cfg, g, {}, x, positions,
                                     shared=params["shared_block"])
             for _ in range(g.count):
-                y, aux = (jax.checkpoint(shared_body)(x) if cfg.remat
-                          else shared_body(x))
-                x = y
-                aux_total = aux_total + aux
+                x, stats = (jax.checkpoint(shared_body)(x) if cfg.remat
+                            else shared_body(x))
+                total = add(total, stats)
             continue
 
         def body(x, lp):
-            y, aux = _apply_layer(cfg, g, lp, x, positions)
-            return constrain_batch(y), aux
+            y, stats = _apply_layer(cfg, g, lp, x, positions)
+            return constrain_batch(y), stats
 
         if cfg.remat:
             pol = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                    if cfg.remat_policy == "dots"
                    else jax.checkpoint_policies.nothing_saveable)
             body = jax.checkpoint(body, policy=pol)
-        x, auxs = jax.lax.scan(body, x, gp)
-        aux_total = aux_total + auxs.sum()
+        x, stats = jax.lax.scan(body, x, gp)
+        total = add(total, jax.tree.map(lambda v: v.sum(0), stats))
     x = apply_norm(cfg, params["final_norm"], x)
-    return x, aux_total
+    return x, total
+
+
+def loss_and_stats(cfg: ModelConfig, params: Dict, batch: Dict
+                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The training loss, and the step's MoE counts over all layers
+    (``moe_assigned``, ``moe_kept``; empty without MoE layers)."""
+    h, stats = backbone(cfg, params, batch)
+    xent = chunked_softmax_xent(cfg, params["embed"], params.get("lm_head"),
+                                h, batch["labels"], batch.get("loss_mask"))
+    counts = {k: stats[k] for k in ("moe_assigned", "moe_kept")} \
+        if cfg.moe is not None else {}
+    return xent + stats["aux"], counts
 
 
 def loss(cfg: ModelConfig, params: Dict, batch: Dict) -> jax.Array:
-    h, aux = backbone(cfg, params, batch)
-    xent = chunked_softmax_xent(cfg, params["embed"], params.get("lm_head"),
-                                h, batch["labels"], batch.get("loss_mask"))
-    return xent + aux
+    return loss_and_stats(cfg, params, batch)[0]
 
 
 def logits_fn(cfg: ModelConfig, params: Dict, batch: Dict) -> jax.Array:

@@ -237,9 +237,13 @@ class Trainer:
                         t0 = time.perf_counter()
                         with span("trainer.put", step=global_step):
                             batch = self._place_batch(batch)
-                        with span("trainer.compute", step=global_step):
+                        with span("trainer.compute", step=global_step) as sp:
                             state, metrics = step_fn(state, batch)
                             jax.block_until_ready((state, metrics))
+                            if sp.is_enabled() and "moe_assigned" in metrics:
+                                sp.set_metadata(
+                                    moe_assigned=int(metrics["moe_assigned"]),
+                                    moe_kept=int(metrics["moe_kept"]))
                         dt = time.perf_counter() - t0
                         loss = float(metrics["loss"])
                         if self.first_step_at is None:

@@ -52,6 +52,46 @@ def test_flash_attention_sweep(B, H, KV, S, T, D, causal, block, dtype):
                                np.asarray(o_ker, np.float32), atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("B,H,S,T,Dqk,Dv,causal,block", [
+    pytest.param(1, 2, 128, 128, 192, 128, True, 64, id="mla-64"),
+    pytest.param(2, 2, 1024, 1024, 192, 128, True, None, id="mla-plan"),
+    pytest.param(1, 2, 256, 1024, 192, 128, True, None, id="mla-offset"),
+    pytest.param(1, 2, 128, 256, 32, 8, False, None, id="narrow-v"),
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_v_head_of_its_own(B, H, S, T, Dqk, Dv, causal, block,
+                                          dtype):
+    """MLA's shapes: v (and the output) at a head size under q's and k's."""
+    q, k = rnd((B, H, S, Dqk), dtype), rnd((B, H, T, Dqk), dtype)
+    v = rnd((B, H, T, Dv), dtype)
+    scale = 1.59 * Dqk ** -0.5
+    # the oracle in float32 on the same (rounded) inputs: bf16 rounding
+    # inside the naive oracle itself would add to the kernel's
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    o_ref = ref.attention_naive(*f32, causal, scale)
+    o_ker = flash_attention_fwd(q, k, v, causal, scale, block_q=block,
+                                block_k=block, interpret=True)
+    assert o_ker.shape == (B, H, S, Dv)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(o_ref, np.float32),
+                               np.asarray(o_ker, np.float32), atol=tol, rtol=tol)
+    o_blk = ref.attention_blockwise(q, k, v, causal, scale, block_q=128,
+                                    block_k=128)
+    np.testing.assert_allclose(np.asarray(o_ref, np.float32),
+                               np.asarray(o_blk, np.float32), atol=tol, rtol=tol)
+
+
+def test_plan_blocks_with_a_v_head_of_its_own():
+    """The KV block is sized for the wider of K and V; a v head size at or
+    under q's leaves the plan of q's head size alone (Granite, D = 64)."""
+    assert plan_blocks(2048, 2048, 64, True, Dv=64) == \
+        plan_blocks(2048, 2048, 64, True)
+    mla = plan_blocks(8192, 8192, 192, True, Dv=128)
+    assert (mla.block_q, mla.block_k, mla.block_c) == (512, 1024, 512)
+    assert plan_blocks(8192, 8192, 64, True, Dv=256).block_k == \
+        plan_blocks(8192, 8192, 256, True).block_k
+
+
 def _live_share(S, T, bq, bc, causal):
     """Share of (q block, kv chunk) pairs holding a score the mask admits,
     counted position by position."""
@@ -188,6 +228,27 @@ def test_attention_custom_vjp_grads():
     g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("S,T,Dqk,Dv", [(64, 64, 32, 32), (64, 64, 48, 16),
+                                       (32, 128, 48, 16)])
+def test_blockwise_ref_grads_match_naive(S, T, Dqk, Dv):
+    """The blockwise VJP (the Pallas forward's backward) against the naive
+    one, at a v head of its own and with queries offset into the keys."""
+    q, k, v = rnd((1, 4, S, Dqk)), rnd((1, 2, T, Dqk)), rnd((1, 2, T, Dv))
+    w = rnd((1, 4, S, Dv))
+
+    def f(fn):
+        return jax.grad(lambda q, k, v: (fn(q, k, v) * w).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    g1 = f(lambda q, k, v: ref.attention_naive(q, k, v, True, 0.3))
+    g2 = f(lambda q, k, v: ref.attention_blockwise(q, k, v, True, 0.3,
+                                                   block_q=16, block_k=32))
+    g3 = f(lambda q, k, v: ops.attention(q, k, v, True, 0.3, impl="interpret"))
+    for a, b, c in zip(g1, g2, g3):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+        np.testing.assert_allclose(a, c, atol=2e-4, rtol=2e-4)
 
 
 def test_mamba2_custom_vjp_grads():

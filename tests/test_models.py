@@ -84,7 +84,7 @@ def test_moe_capacity_and_dropless_match_oracle():
     k = jax.random.PRNGKey(3)
     p = mlpm.moe_init(cfg, k)
     x = jax.random.normal(k, (2, 64, cfg.d_model), jnp.float32)
-    y_cap, aux = mlpm.moe_apply(cfg, p, x)
+    y_cap, stats = mlpm.moe_apply(cfg, p, x)
     y_oracle = mlpm.moe_apply_dense_oracle(cfg, p, x)
     np.testing.assert_allclose(np.asarray(y_cap), np.asarray(y_oracle),
                                atol=2e-4, rtol=2e-4)
@@ -92,7 +92,7 @@ def test_moe_capacity_and_dropless_match_oracle():
     y_dl, _ = mlpm.moe_apply(cfg2, p, x)
     np.testing.assert_allclose(np.asarray(y_dl), np.asarray(y_oracle),
                                atol=2e-4, rtol=2e-4)
-    assert float(aux) >= 0
+    assert float(stats["aux"]) >= 0
 
 
 def test_moe_capacity_drops_bounded():
@@ -154,3 +154,108 @@ def test_chunked_loss_matches_full_softmax():
     lab = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
     l2 = (lse - lab).mean()
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
+
+
+def test_yarn_frequencies_and_scale_as_published():
+    """YaRN (DeepSeek-V2's rope_scaling) against a plain transcription of
+    the published formulas: correction range, linear ramp, mscale."""
+    import math
+
+    from repro.configs.deepseek_v2_lite import YARN
+    from repro.models.common import rope_freqs, rope_mscale
+    from repro.models.config import yarn_mscale
+
+    dim, base = 64, 10000.0
+    extra = 1.0 / base ** (np.arange(0, dim, 2) / dim)
+
+    def corr(rot):
+        return dim * math.log(4096 / (rot * 2 * math.pi)) / (2 * math.log(base))
+
+    low, high = max(math.floor(corr(32)), 0), min(math.ceil(corr(1)), dim - 1)
+    assert (low, high) == (10, 23)
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0, 1)
+    want = extra / 40 * ramp + extra * (1 - ramp)
+    np.testing.assert_allclose(np.asarray(rope_freqs(dim, base, YARN)), want,
+                               rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(rope_freqs(dim, base)), extra,
+                               rtol=1e-6)
+    # mscale == mscale_all_dim: cos and sin unscaled; the softmax scale
+    # takes mscale(40, 0.707)^2 = 1.5897
+    assert rope_mscale(YARN) == 1.0
+    assert yarn_mscale(40, 0.707) ** 2 == pytest.approx(1.58966, rel=1e-4)
+
+
+def test_model_config_takes_nested_groups_as_mappings():
+    from repro.models.config import (MLAConfig, ModelConfig, MoEConfig,
+                                     YarnScaling)
+
+    cfg = ModelConfig(
+        name="m", vocab_size=256, d_model=64, n_layers=2, n_heads=4,
+        n_kv_heads=4, d_ff=128, block_pattern=["mla", "mla"],
+        mla={"q_lora": 0, "kv_lora": 16, "qk_nope": 8, "qk_rope": 8,
+             "v_head": 8},
+        moe={"num_experts": 8, "top_k": 2, "d_expert": 16,
+             "experts_held": 2, "expert_offset": 6},
+        rope_scaling={"type": "yarn", "factor": 40,
+                      "original_max_position_embeddings": 4096})
+    assert cfg.block_pattern == ("mla", "mla")
+    assert isinstance(cfg.mla, MLAConfig) and cfg.mla.q_lora == 0
+    assert isinstance(cfg.moe, MoEConfig) and cfg.moe.held == 2
+    assert isinstance(cfg.rope_scaling, YarnScaling)
+    assert cfg == replace(cfg)          # frozen, hashable groups
+    with pytest.raises(ValueError):
+        MoEConfig(num_experts=8, top_k=2, d_expert=16, experts_held=4,
+                  expert_offset=6)
+    with pytest.raises(ValueError):
+        ModelConfig(name="m", vocab_size=256, d_model=64, n_layers=1,
+                    n_heads=4, n_kv_heads=4, d_ff=128,
+                    rope_scaling={"type": "linear", "factor": 2,
+                                  "original_max_position_embeddings": 4096})
+
+
+@pytest.mark.parametrize("norm_topk,routed_scale", [(False, 1.0), (False, 16.0),
+                                                    (True, 2.5)])
+def test_moe_gates_raw_or_renormalised_and_scaled(norm_topk, routed_scale):
+    """Capacity and dropless paths against the dense oracle with DeepSeek's
+    gate options, and the counts of a layer that drops nothing."""
+    cfg = get_config("granite_moe_3b_a800m", smoke=True)
+    moe = replace(cfg.moe, dropless=False, capacity_factor=8.0, group_tokens=32,
+                  norm_topk=norm_topk, routed_scale=routed_scale)
+    cfg = replace(cfg, moe=moe)
+    k = jax.random.PRNGKey(5)
+    p = mlpm.moe_init(cfg, k)
+    x = jax.random.normal(k, (2, 64, cfg.d_model), jnp.float32)
+    y_cap, stats = mlpm.moe_apply(cfg, p, x)
+    y_oracle = mlpm.moe_apply_dense_oracle(cfg, p, x)
+    np.testing.assert_allclose(np.asarray(y_cap), np.asarray(y_oracle),
+                               atol=2e-4 * routed_scale, rtol=2e-4)
+    assert int(stats["moe_assigned"]) == int(stats["moe_kept"]) == 2 * 64 * 2
+    cfg2 = replace(cfg, moe=replace(moe, dropless=True))
+    y_dl, _ = mlpm.moe_apply(cfg2, p, x)
+    np.testing.assert_allclose(np.asarray(y_dl), np.asarray(y_oracle),
+                               atol=2e-4 * routed_scale, rtol=2e-4)
+
+
+def test_moe_share_of_held_experts():
+    """A chip holding experts 2 and 3 of 8: the capacity path agrees with
+    the oracle's share and counts only those experts' picks; the dropless
+    path holds every expert."""
+    cfg = get_config("granite_moe_3b_a800m", smoke=True)
+    full = replace(cfg.moe, dropless=False, capacity_factor=8.0,
+                   group_tokens=32)
+    k = jax.random.PRNGKey(6)
+    p = mlpm.moe_init(replace(cfg, moe=full), k)
+    x = jax.random.normal(k, (2, 64, cfg.d_model), jnp.float32)
+    share = replace(full, experts_held=2, expert_offset=2)
+    ps = dict(p, **{n: p[n][2:4] for n in ("wi", "wg", "wo")})
+    c = replace(cfg, moe=share)
+    y, stats = mlpm.moe_apply(c, ps, x)
+    y_oracle = mlpm.moe_apply_dense_oracle(c, ps, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_oracle),
+                               atol=2e-4, rtol=2e-4)
+    ids = jax.lax.top_k(jax.nn.softmax(
+        x.reshape(-1, cfg.d_model) @ p["router"], -1), 2)[1]
+    want = int(((ids >= 2) & (ids < 4)).sum())
+    assert int(stats["moe_assigned"]) == int(stats["moe_kept"]) == want > 0
+    with pytest.raises(NotImplementedError):
+        mlpm.moe_apply(replace(c, moe=replace(share, dropless=True)), ps, x)

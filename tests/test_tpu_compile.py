@@ -107,3 +107,30 @@ def test_attention_custom_vjp_compiles_at_granite_widths(one_chip):
 
     step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
     _assert_granite_call(step.lower(q, kv, kv).compile())
+
+
+MLA_QK, MLA_V = (2, 16, 8192, 192), (2, 16, 8192, 128)  # V2-Lite, batch 2 x 8k
+
+
+def _assert_mla_call(compiled):
+    """q and k at bf16[2,16,8192,192], v and o at bf16[2,16,8192,128]: the
+    strings the benchmark's ``mla_attn_roofline`` reader finds it by."""
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert any(c.count("bf16[2,16,8192,192]") >= 2 and "bf16[2,16,8192,128]" in c
+               for c in calls), calls
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "custom_vjp"])
+def test_attention_compiles_at_deepseek_v2_lite_widths(one_chip, grad):
+    """Latent attention's shapes: v at its own head size under q's and k's,
+    the forward alone and with the reference backward."""
+    q = _sds(MLA_QK, jnp.bfloat16, one_chip)
+    v = _sds(MLA_V, jnp.bfloat16, one_chip)
+
+    def fwd(q, k, v):
+        return ops.attention(q, k, v, scale=0.115, impl="pallas")
+
+    fn = jax.value_and_grad(lambda q, k, v: fwd(q, k, v).astype(
+        jnp.float32).sum(), argnums=(0, 1, 2)) if grad else fwd
+    _assert_mla_call(jax.jit(fn).lower(q, q, v).compile())
