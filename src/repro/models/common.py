@@ -3,7 +3,8 @@
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
+from functools import partial
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -190,38 +191,111 @@ def chunked_softmax_xent(cfg: ModelConfig, emb: Dict, out_w: Optional[jax.Array]
     Scans over sequence chunks; each chunk computes (B,C,V) logits, its
     log-sum-exp and the label logit, then discards them.  With V up to
     256 k this is the difference between fitting and not fitting.
+
+    Differentiated, the same scan also computes the gradient: the loss is
+    the step's terminal scalar, so a chunk's ``d loss / d logits`` is known
+    once its log-sum-exp is.  The VJP keeps only ``d loss / d h`` (B,S,D,
+    in h's dtype) and ``d loss / d w`` (V,D, float32), never the chunks'
+    logits, and the backward scales them by the incoming cotangent.
     """
     B, S, D = h.shape
     C = min(cfg.loss_chunk, S)
     if S % C:
         raise ValueError("seq len must divide loss_chunk")
-    nchunks = S // C
     w = emb["tok"] if cfg.tie_embeddings or out_w is None else out_w
-    wf = w.astype(jnp.float32)
     if mask is None:
         mask = jnp.ones((B, S), jnp.float32)
-    hc = h.reshape(B, nchunks, C, D)
-    lc = labels.reshape(B, nchunks, C)
-    mc = mask.reshape(B, nchunks, C)
+    spec = _XentSpec(C, cfg.vocab_size, cfg.padded_vocab,
+                     float(cfg.logit_softcap))
+    return _xent(spec, h, w, labels, mask)
 
-    def chunk_loss(carry, i):
-        hh = hc[:, i].astype(jnp.float32)           # (B,C,D)
-        logits = jnp.einsum("bcd,vd->bcv", hh, wf)  # (B,C,V)
-        logits = constrain_dims(logits, {0: "dp", 2: "model"})
-        if cfg.logit_softcap > 0:
-            logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
-        if cfg.padded_vocab != cfg.vocab_size:
-            pad_mask = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
-            logits = jnp.where(pad_mask[None, None, :], logits, -1e30)
-        lse = jax.nn.logsumexp(logits, axis=-1)     # (B,C)
-        lab = jnp.take_along_axis(logits, lc[:, i][..., None], axis=-1)[..., 0]
-        nll = (lse - lab) * mc[:, i]
-        return carry + nll.sum(), None
+
+class _XentSpec(NamedTuple):
+    chunk: int
+    vocab: int
+    padded_vocab: int
+    softcap: float
+
+
+def _by_chunk(spec: _XentSpec, x: jax.Array) -> jax.Array:
+    """(B,S,...) -> (B,nchunks,C,...); the scans index axis 1 in place."""
+    B, S = x.shape[:2]
+    return x.reshape(B, S // spec.chunk, spec.chunk, *x.shape[2:])
+
+
+def _chunk_logits(spec: _XentSpec, hh: jax.Array, wf: jax.Array):
+    """(B,C,V) float32 logits of one chunk, softcapped and with padded
+    vocab rows at -1e30; and the softcap's tanh (None without one)."""
+    logits = jnp.einsum("bcd,vd->bcv", hh.astype(jnp.float32), wf)
+    logits = constrain_dims(logits, {0: "dp", 2: "model"})
+    t = None
+    if spec.softcap > 0:
+        t = jnp.tanh(logits / spec.softcap)
+        logits = t * spec.softcap
+    if spec.padded_vocab != spec.vocab:
+        pad_mask = jnp.arange(spec.padded_vocab) < spec.vocab
+        logits = jnp.where(pad_mask[None, None, :], logits, -1e30)
+    return logits, t
+
+
+def _chunk_nll(logits: jax.Array, lab: jax.Array, m: jax.Array):
+    """Masked per-token NLL (B,C) and the log-sum-exp it used."""
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    lab_logit = jnp.take_along_axis(logits, lab[..., None], axis=-1)[..., 0]
+    return (lse - lab_logit) * m, lse
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _xent(spec: _XentSpec, h, w, labels, mask):
+    wf = w.astype(jnp.float32)
+    hc, lc, mc = (_by_chunk(spec, x) for x in (h, labels, mask))
+
+    def chunk_loss(total, i):
+        logits, _ = _chunk_logits(spec, hc[:, i], wf)
+        nll, _ = _chunk_nll(logits, lc[:, i], mc[:, i])
+        return total + nll.sum(), None
 
     total, _ = jax.lax.scan(chunk_loss, jnp.zeros((), jnp.float32),
-                            jnp.arange(nchunks))
+                            jnp.arange(hc.shape[1]))
+    return total / jnp.maximum(mask.sum(), 1.0)
+
+
+def _xent_fwd(spec: _XentSpec, h, w, labels, mask):
+    wf = w.astype(jnp.float32)
+    hc, lc, mc = (_by_chunk(spec, x) for x in (h, labels, mask))
     denom = jnp.maximum(mask.sum(), 1.0)
-    return total / denom
+
+    def chunk_loss_grad(carry, i):
+        total, dh, dw = carry
+        hh, lab, m = hc[:, i], lc[:, i], mc[:, i]
+        logits, t = _chunk_logits(spec, hh, wf)
+        nll, lse = _chunk_nll(logits, lab, m)
+        onehot = jnp.arange(spec.padded_vocab) == lab[..., None]
+        dlogits = ((jnp.exp(logits - lse[..., None]) - onehot)
+                   * (m / denom)[..., None])
+        if t is not None:
+            dlogits = dlogits * (1.0 - t * t)
+        dlogits = constrain_dims(dlogits, {0: "dp", 2: "model"})
+        dh = dh.at[:, i].set(
+            jnp.einsum("bcv,vd->bcd", dlogits, wf).astype(h.dtype))
+        dw = dw + jnp.einsum("bcv,bcd->vd", dlogits, hh.astype(jnp.float32))
+        return (total + nll.sum(), dh, dw), None
+
+    (total, dh, dw), _ = jax.lax.scan(
+        chunk_loss_grad,
+        (jnp.zeros((), jnp.float32), jnp.zeros_like(hc),
+         jnp.zeros(w.shape, jnp.float32)),
+        jnp.arange(hc.shape[1]))
+    return total / denom, (dh.reshape(h.shape), dw, w)
+
+
+def _xent_bwd(spec: _XentSpec, res, g):
+    dh, dw, w = res
+    return ((g * dh.astype(jnp.float32)).astype(dh.dtype),
+            (g * dw).astype(w.dtype), None, None)
+
+
+_xent.defvjp(_xent_fwd, _xent_bwd)
 
 
 _SHARDING_PROFILE = "tp"  # "tp" | "fsdp" — set by the launcher

@@ -140,20 +140,107 @@ def test_mrope_equals_rope_for_text_positions():
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5)
 
 
-def test_chunked_loss_matches_full_softmax():
+def _check_chunked_loss(*, head="emb", chunk=64, pad=0, softcap=0.0,
+                        mask="none", dtype=jnp.float32, w_scale=0.02):
+    """Loss and gradients (h, embedding, output head) of the chunked loss,
+    jitted under ``value_and_grad(has_aux=True)`` as the train step calls
+    it, against autodiff of the full-softmax ``lm_head_logits`` loss.
+
+    ``head``: "emb" (no output weight: the embedding is the head), "tied"
+    (``tie_embeddings``, an output weight given and ignored), "untied"."""
     from repro.models.common import chunked_softmax_xent, lm_head_logits
 
-    cfg = get_config("tinyllama_1_1b", smoke=True)
-    k = jax.random.PRNGKey(0)
-    emb = {"tok": jax.random.normal(k, (cfg.padded_vocab, cfg.d_model)) * 0.02}
-    h = jax.random.normal(k, (2, 64, cfg.d_model), jnp.float32)
-    labels = jax.random.randint(k, (2, 64), 0, cfg.vocab_size)
-    l1 = chunked_softmax_xent(cfg, emb, None, h, labels)
-    logits = lm_head_logits(cfg, emb, None, h)
-    lse = jax.nn.logsumexp(logits, -1)
-    lab = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
-    l2 = (lse - lab).mean()
-    np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
+    base = get_config("tinyllama_1_1b", smoke=True)
+    cfg = replace(base, loss_chunk=chunk, logit_softcap=softcap,
+                  vocab_size=base.padded_vocab - pad,
+                  tie_embeddings=head == "tied")
+    assert cfg.padded_vocab == base.padded_vocab
+    Bt, St, V, D = 2, 64, cfg.padded_vocab, cfg.d_model
+    k = jax.random.split(jax.random.PRNGKey(0), 5)
+    emb = {"tok": (jax.random.normal(k[0], (V, D)) * w_scale).astype(dtype)}
+    out_w = (None if head == "emb"
+             else (jax.random.normal(k[1], (V, D)) * w_scale).astype(dtype))
+    h = jax.random.normal(k[2], (Bt, St, D), jnp.float32).astype(dtype)
+    labels = jax.random.randint(k[3], (Bt, St), 0, cfg.vocab_size)
+    m = {"none": None,
+         "zeros": (jax.random.uniform(k[4], (Bt, St)) > 0.3).astype(jnp.float32),
+         "all_zero": jnp.zeros((Bt, St), jnp.float32)}[mask]
+
+    def chunked(h, emb, out_w):
+        loss = chunked_softmax_xent(cfg, emb, out_w, h, labels, m)
+        return 2.0 * loss, loss
+
+    def full(h, emb, out_w):
+        logits = lm_head_logits(cfg, emb, out_w, h)
+        lse = jax.nn.logsumexp(logits, -1)
+        lab = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
+        mm = jnp.ones((Bt, St), jnp.float32) if m is None else m
+        loss = ((lse - lab) * mm).sum() / jnp.maximum(mm.sum(), 1.0)
+        return 2.0 * loss, loss
+
+    def run(f):
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True))(
+            h, emb, out_w)
+
+    (v1, l1), g1 = run(chunked)
+    (v2, l2), g2 = run(full)
+    tol = 1e-5 if dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(float(v1), float(v2), rtol=1e-5, atol=1e-6)
+    if mask == "all_zero":
+        assert float(l1) == 0.0
+    leaves1, leaves2 = jax.tree.leaves(g1), jax.tree.leaves(g2)
+    assert len(leaves1) == len(leaves2) == (2 if head == "emb" else 3)
+    for a, b in zip(leaves1, leaves2):
+        assert a.dtype == b.dtype == dtype
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        scale = max(float(np.abs(b).max()), 1e-30)
+        np.testing.assert_allclose(a, b, rtol=tol, atol=tol * scale)
+    if head == "tied":
+        assert not np.asarray(g1[2], np.float32).any()  # the head is the embedding
+
+
+def test_chunked_loss_matches_full_softmax():
+    _check_chunked_loss()
+
+
+@pytest.mark.parametrize("case", [
+    dict(head="untied", chunk=16),
+    dict(head="tied", chunk=16, dtype=jnp.bfloat16),
+    dict(pad=7, chunk=16, mask="zeros"),
+    dict(softcap=30.0, chunk=8, w_scale=0.5),
+    dict(softcap=30.0, pad=7, chunk=16, head="untied", mask="zeros",
+         dtype=jnp.bfloat16, w_scale=0.5),
+    dict(mask="all_zero", chunk=16),
+    dict(mask="zeros", chunk=64, dtype=jnp.bfloat16),
+], ids=["untied-4chunks", "tied-bf16", "padded-vocab-masked",
+        "softcap-8chunks", "softcap-padded-untied-bf16", "all-zero-mask",
+        "masked-1chunk-bf16"])
+def test_chunked_loss_and_grads_match_full_softmax(case):
+    _check_chunked_loss(**case)
+
+
+def test_chunked_loss_grad_keeps_no_stacked_logits():
+    """The differentiated loss keeps no chunk's (B,C,V) float32 logits for
+    the backward: its compiled temporaries stay below one stacked-logits
+    buffer (nchunks x B x C x V x 4 bytes), which autodiff through the
+    chunk scan would write."""
+    from repro.models.common import chunked_softmax_xent
+
+    Bt, St, C = 2, 1024, 64
+    cfg = replace(get_config("tinyllama_1_1b", smoke=True), loss_chunk=C,
+                  vocab_size=4096)
+    V, D = cfg.padded_vocab, cfg.d_model
+
+    def loss(h, w, labels):
+        return chunked_softmax_xent(cfg, {"tok": w}, None, h, labels)
+
+    args = (jax.ShapeDtypeStruct((Bt, St, D), jnp.bfloat16),
+            jax.ShapeDtypeStruct((V, D), jnp.bfloat16),
+            jax.ShapeDtypeStruct((Bt, St), jnp.int32))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*args).compile()
+    stacked = (St // C) * Bt * C * V * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < stacked
 
 
 def test_yarn_frequencies_and_scale_as_published():
