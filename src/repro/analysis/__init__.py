@@ -1,15 +1,12 @@
-"""Analysis tools: HLO cost extraction, roofline, and the foreaction-graph
-miner that folds recorded syscall traces into speculatable graphs."""
+"""Analysis tools: the foreaction-graph miner that folds recorded syscall
+traces into speculatable graphs, and its online re-miner."""
 
-from .hlo import HloSummary, analyze_hlo
 from .mine import (MinedGraph, ReplayMismatch, UnminableTrace, UnsoundGraph,
                    mine_and_validate, mine_traces, preissue_overlap,
                    replay_trace, synthesize_trace)
 from .remine import ReMineConfig, ReMiner
-from .roofline import HW, RooflineTerms, roofline_from_report
 
 __all__ = [
-    "HloSummary", "analyze_hlo", "HW", "RooflineTerms", "roofline_from_report",
     "MinedGraph", "ReplayMismatch", "UnminableTrace", "UnsoundGraph",
     "mine_and_validate", "mine_traces", "replay_trace",
     "preissue_overlap", "synthesize_trace", "ReMineConfig", "ReMiner",

@@ -1,20 +1,16 @@
 """Assigned-architecture registry: ``--arch <id>`` resolution.
 
-Each module defines ``full()`` (the published configuration, exercised
-only via the dry-run) and ``smoke()`` (a reduced same-family config that
-runs a real forward/train step on CPU).
-
-Shapes (assignment): every arch pairs with the LM shape set below;
-``decode_*``/``long_*`` lower serve_step (single new token against a
-seq_len cache).  ``long_500k`` requires sub-quadratic sequence mixing and
-is only runnable for the SSM/hybrid archs (see ``SKIP_CELLS``).
+Each module defines ``full()`` (the published configuration) and
+``smoke()`` (a reduced same-family config that runs a real forward/train
+step on CPU).  ``SHAPES`` is the LM shape set the sharding rules must
+divide at the published widths.
 """
 
 from __future__ import annotations
 
 import importlib
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import Dict
 
 from repro.models.config import ModelConfig
 
@@ -63,16 +59,6 @@ SHAPES: Dict[str, ShapeSpec] = {
     "long_500k": ShapeSpec("long_500k", 524288, 1, "decode"),
 }
 
-#: archs whose sequence mixing is sub-quadratic end-to-end (long_500k runs)
-LONG_CONTEXT_OK = {"zamba2_1_2b", "rwkv6_7b"}
-
-#: (arch, shape) cells skipped, with the reason recorded in EXPERIMENTS.md
-SKIP_CELLS: Dict[Tuple[str, str], str] = {
-    (a, "long_500k"): "pure full-attention arch: O(S^2) prefill / O(S) KV "
-                      "cache at 524k is out of scope per assignment"
-    for a in ARCH_IDS if a not in LONG_CONTEXT_OK
-}
-
 
 def resolve(arch: str) -> str:
     return ALIASES.get(arch, arch)
@@ -81,13 +67,3 @@ def resolve(arch: str) -> str:
 def get_config(arch: str, smoke: bool = False) -> ModelConfig:
     mod = importlib.import_module(f"repro.configs.{resolve(arch)}")
     return mod.smoke() if smoke else mod.full()
-
-
-def cells(include_skipped: bool = False) -> List[Tuple[str, str]]:
-    out = []
-    for a in ARCH_IDS:
-        for s in SHAPES:
-            if not include_skipped and (a, s) in SKIP_CELLS:
-                continue
-            out.append((a, s))
-    return out

@@ -29,8 +29,8 @@ from .lanes import SubmissionLane
 from .buffers import BufferLease, BufferPool
 from .completion import CompletionPool, completion_pool
 from .device import (
-    Device, DeviceProfile, MemDevice, NVME_PROFILE, OSDevice, REMOTE_PROFILE,
-    ShardedDevice, SimulatedDevice,
+    Device, DeviceProfile, MemDevice, OSDevice, REMOTE_PROFILE, ShardedDevice,
+    SimulatedDevice,
 )
 from .engine import (DepthController, FuturePoisoned, GraphMismatch,
                      SessionStats, SpecSession)
@@ -48,8 +48,8 @@ __all__ = [
     "ThreadPoolBackend", "make_backend",
     "BufferLease", "BufferPool",
     "CompletionPool", "completion_pool",
-    "Device", "DeviceProfile", "MemDevice", "NVME_PROFILE", "OSDevice",
-    "REMOTE_PROFILE", "ShardedDevice", "SimulatedDevice",
+    "Device", "DeviceProfile", "MemDevice", "OSDevice", "REMOTE_PROFILE",
+    "ShardedDevice", "SimulatedDevice",
     "DepthController", "FuturePoisoned", "GraphMismatch", "SessionStats",
     "SpecSession",
     "BranchNode", "ForeactionGraph", "GraphBuilder", "SyscallNode",

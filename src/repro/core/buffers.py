@@ -192,7 +192,7 @@ class BufferPool:
     like io_uring's fixed registration: when the budget is exhausted,
     :meth:`lease` returns ``None`` and the request falls back to the
     allocate-per-request path instead of blocking.  Thread-safe; stats are
-    exposed to benchmarks (``bench_overhead``) and tests.
+    exposed to tests.
 
     **Per-tenant budgets** (multi-tenant serving): when a lease names a
     tenant, the class size is charged against that tenant's

@@ -40,7 +40,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 @dataclass
 class DeviceStats:
-    """Operation counters, used by benchmarks and tests."""
+    """Operation counters, read by tests."""
 
     ops: int = 0
     read_bytes: int = 0
@@ -347,9 +347,7 @@ class DeviceProfile:
     The default profile models the storage tier a TPU-pod *host* actually
     talks to — a remote/parallel blob store (ms-scale per-op latency, high
     aggregate parallelism).  Python's ``time.sleep`` granularity (~100 us)
-    makes microsecond-scale NVMe emulation unmeasurable in-process, so the
-    paper's 60 us-class NVMe profile is provided as :data:`NVME_PROFILE`
-    for reference but benchmarks default to :data:`REMOTE_PROFILE`.  The
+    makes the paper's microsecond-scale NVMe unmeasurable in-process; the
     *shape* of the effect (throughput scales with queue depth until the
     device's internal parallelism saturates) is identical — only the time
     constant changes.
@@ -370,7 +368,7 @@ class DeviceProfile:
     def raw_bandwidth_bytes(self) -> float:
         """Aggregate streaming ceiling (bytes/s) with every channel busy on
         infinitely large requests — the denominator for 'fraction of raw
-        device bandwidth' in ``bench_bandwidth``."""
+        device bandwidth'."""
         if self.per_byte <= 0:
             return float("inf")
         return self.channels / self.per_byte
@@ -378,17 +376,6 @@ class DeviceProfile:
 
 #: default: remote blob / parallel-FS tier of a training cluster
 REMOTE_PROFILE = DeviceProfile()
-
-#: the paper's Toshiba NVMe (~60 MB/s @ QD1/4 KB => ~66 us/op; ~1.2 GB/s peak).
-#: Useful for unit tests of the cost model, too fine-grained to benchmark
-#: under Python sleep granularity.
-NVME_PROFILE = DeviceProfile(
-    channels=16,
-    base_latency=60e-6,
-    per_byte=1.2e-9,
-    crossing_cost=2.5e-6,
-    metadata_latency=40e-6,
-)
 
 
 def _precise_sleep(dur: float) -> None:

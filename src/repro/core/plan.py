@@ -302,7 +302,7 @@ def _lower(graph: ForeactionGraph, depth_mode: str) -> GraphPlan:
 # ---------------------------------------------------------------------------
 _cache: Dict[Tuple[int, str], GraphPlan] = {}
 _cache_lock = threading.Lock()
-#: cache-effectiveness counters (tests + bench_overhead assert on these)
+#: cache-effectiveness counters (tests assert on these)
 stats = {"compiles": 0, "hits": 0}
 
 

@@ -17,7 +17,6 @@ device compute:
 Each kernel ships ``<name>.py`` (pl.pallas_call + BlockSpec), a jitted
 wrapper in :mod:`repro.kernels.ops`, and a pure-jnp oracle in
 :mod:`repro.kernels.ref`.  On this CPU container kernels are validated with
-``interpret=True``; model code defaults to the memory-efficient jnp
-reference implementations (which is also what the dry-run lowers, keeping
-cost/memory analysis faithful on the CPU backend).
+``interpret=True``; off the TPU, model code defaults to the
+memory-efficient jnp reference implementations.
 """

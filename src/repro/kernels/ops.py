@@ -3,7 +3,7 @@
 ``impl``:
 * ``"naive"``      — simplest oracle (tests, tiny shapes)
 * ``"ref"``        — memory-efficient pure-XLA twin (blockwise / chunked);
-                     differentiable; the default on CPU and in the dry-run
+                     differentiable; the default on CPU
 * ``"pallas"``     — the TPU kernel (compiled via Mosaic)
 * ``"interpret"``  — the TPU kernel executed in interpret mode (CPU CI)
 * ``"auto"``       — pallas on TPU, ref elsewhere

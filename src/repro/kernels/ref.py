@@ -5,8 +5,8 @@ Two flavors where it matters:
 * ``*_naive`` — the simplest possible semantics (materializes S x S scores,
   steps the recurrence token by token).  These define correctness.
 * ``attention_blockwise`` / chunked scans — memory-efficient pure-XLA
-  implementations used by the model plane on CPU and in the dry-run
-  (numerically equal to the naive versions up to float assoc.).
+  implementations used by the model plane on CPU (numerically equal to
+  the naive versions up to float assoc.).
 """
 
 from __future__ import annotations
@@ -61,7 +61,7 @@ def attention_blockwise(
     """Online-softmax attention in pure jnp (never materializes S x T).
 
     This is the 'flash-in-XLA' path the model plane uses for long
-    sequences on the CPU backend and in the dry-run; the Pallas kernel in
+    sequences on the CPU backend; the Pallas kernel in
     :mod:`repro.kernels.flash_attention` is the TPU fast path with the
     same math.
     """

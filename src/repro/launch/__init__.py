@@ -1,2 +1,2 @@
-"""Distributed launch plane: production mesh, sharding rules, train/serve
-steps, input specs and the multi-pod dry-run driver."""
+"""Distributed launch plane: host mesh, sharding rules, train/serve steps
+and the train, serve and I/O-server entry points."""

@@ -1,9 +1,6 @@
-"""Production mesh construction.
-
-Single pod: 16x16 = 256 chips over ("data", "model").
-Multi-pod:  2x16x16 = 512 chips over ("pod", "data", "model") — the pod
-axis is an outer data axis (per-pod FSDP, cross-pod gradient all-reduce
-over DCN), which is why batch specs shard over ("pod", "data") jointly.
+"""Mesh construction over ("data", "model"); a "pod" axis, where a mesh
+has one, is an outer data axis, which is why batch specs shard over
+("pod", "data") jointly.
 
 Defined as functions so importing this module never touches jax device
 state (device count is locked at first backend init).
@@ -18,12 +15,6 @@ import jax
 
 def _auto(n: int) -> tuple:
     return (jax.sharding.AxisType.Auto,) * n
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(devices: Optional[Sequence] = None):

@@ -41,8 +41,7 @@ def _key(name: str) -> str:
 PARAM_RULES: List[Tuple[str, List[Tuple[int, str]]]] = [
     # vocab on "model" ONLY: sharding the d_model dim of the embedding over
     # "data" makes XLA psum (B,C,V)-sized logits partial-products in the
-    # chunked loss — 100+ GiB of all-reduce per step (measured in the
-    # dry-run, see EXPERIMENTS.md §Perf iteration 1).
+    # chunked loss — 100+ GiB of all-reduce per step on a 16x16 mesh.
     (r"embed.*tok", [(-2, "model")]),
     (r"lm_head", [(-1, "model")]),
     (r"pos_dec", [(-2, "data")]),

@@ -1,8 +1,8 @@
-"""Train / prefill / decode step builders (pjit-able pure functions)."""
+"""Train-step and generation builders (pjit-able pure functions)."""
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
@@ -16,12 +16,6 @@ def make_train_state(model: Model, opt_cfg: AdamWConfig, rng) -> Dict[str, Any]:
     return {"params": params, "opt": adamw_init(opt_cfg, params)}
 
 
-def train_state_shape(model: Model, opt_cfg: AdamWConfig) -> Dict[str, Any]:
-    """ShapeDtypeStruct tree of the train state — no allocation."""
-    return jax.eval_shape(
-        lambda r: make_train_state(model, opt_cfg, r), jax.random.PRNGKey(0))
-
-
 def make_train_step(model: Model, opt_cfg: AdamWConfig):
     def train_step(state: Dict[str, Any], batch: Dict[str, jax.Array]):
         (loss, counts), grads = jax.value_and_grad(
@@ -32,20 +26,6 @@ def make_train_step(model: Model, opt_cfg: AdamWConfig):
         return {"params": new_params, "opt": new_opt}, metrics
 
     return train_step
-
-
-def make_prefill_step(model: Model, max_len: int):
-    def prefill_step(params, batch):
-        return model.prefill(params, batch, max_len)
-
-    return prefill_step
-
-
-def make_decode_step(model: Model):
-    def decode_step(params, cache, token, pos):
-        return model.decode_step(params, cache, token, pos)
-
-    return decode_step
 
 
 def make_generate_loop(model: Model, steps: int):

@@ -53,7 +53,7 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap.add_argument("--no-restore", action="store_true")
     ap.add_argument("--serial-ckpt", action="store_true",
                     help="disable write-behind checkpointing (save blocks "
-                         "the training thread; the bench_write baseline)")
+                         "the training thread; the serial baseline)")
     ap.add_argument("--kill-at", type=int, default=0,
                     help="simulate a node failure at this step")
     ap.add_argument("--keep-last", type=int, default=3,

@@ -56,8 +56,8 @@ def _qkv(cfg: ModelConfig, p: Dict, x: jax.Array, positions) -> Tuple:
     # MQA), q falls back to SEQUENCE sharding (context parallelism) and
     # k/v stay replicated over model.  Never shard head_dim: it is the
     # attention contraction dim, and sharding it makes GSPMD psum
-    # (B,H,S,block) logits per kv block — measured at ~6 TiB/device for
-    # qwen prefill_32k (EXPERIMENTS §Perf it. 8).
+    # (B,H,S,block) logits per kv block — ~6 TiB/device for qwen at a
+    # 32k prefill on a 16x16 mesh.
     q = constrain_dims(q, {0: "dp", 2: "model", 1: "model"})
     k = constrain_dims(k, {0: "dp", 2: "model"})
     v = constrain_dims(v, {0: "dp", 2: "model"})

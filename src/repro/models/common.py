@@ -298,41 +298,22 @@ def _xent_bwd(spec: _XentSpec, res, g):
 _xent.defvjp(_xent_fwd, _xent_bwd)
 
 
-_SHARDING_PROFILE = "tp"  # "tp" | "fsdp" — set by the launcher
-
-
-def set_sharding_profile(profile: str) -> None:
-    """"tp": model axis shards hidden activation dims (Megatron-style).
-    "fsdp": model axis is an extra data/param-shard axis; activation
-    constraints on "model" become no-ops and batch dims may shard over it.
-    Chosen per (arch x shape); see EXPERIMENTS.md §Perf."""
-    global _SHARDING_PROFILE
-    assert profile in ("tp", "fsdp")
-    _SHARDING_PROFILE = profile
-
-
-def get_sharding_profile() -> str:
-    return _SHARDING_PROFILE
-
-
 def _dp_axes(mesh) -> tuple:
-    names = ["pod", "data"]
-    if _SHARDING_PROFILE == "fsdp":
-        names.append("model")
-    return tuple(a for a in names if a in mesh.axis_names)
+    return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
 def constrain_dims(x: jax.Array, assignments: Dict[int, str]) -> jax.Array:
     """Pin activation dims to mesh axes (no-op outside a mesh context).
 
     ``assignments`` maps dim -> role, role in {"dp", "model"}.  "dp" is all
-    data axes (("pod","data") on the multi-pod mesh).  A dim whose size
-    does not divide the axis is silently skipped, so the same model code
-    works for MQA (kv=1), 28-head attention, 40-expert MoE, etc.
+    data axes (("pod","data") on a multi-pod mesh); "model" shards hidden
+    activation dims Megatron-style.  A dim whose size does not divide the
+    axis is silently skipped, so the same model code works for MQA (kv=1),
+    28-head attention, 40-expert MoE, etc.
 
     Without these anchors GSPMD tends to resolve ambiguous einsum
-    shardings by replicating the tensor-parallel dim — measured as a 16x
-    per-device FLOP inflation in the dry-run (EXPERIMENTS.md §Perf it. 2).
+    shardings by replicating the tensor-parallel dim, which multiplies the
+    per-device FLOPs by the model axis's size.
     """
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
@@ -348,8 +329,6 @@ def constrain_dims(x: jax.Array, assignments: Dict[int, str]) -> jax.Array:
             # fallback chain: all data axes, then progressively fewer
             candidates = [ax[:k] for k in range(len(ax), 0, -1)]
         else:
-            if _SHARDING_PROFILE == "fsdp":
-                continue  # model axis belongs to the data pool under fsdp
             if role not in mesh.axis_names:
                 continue
             candidates = [(role,)]

@@ -152,7 +152,7 @@ class ModelConfig:
     remat: bool = True
     # "nothing": full recompute (min memory); "dots": keep matmul
     # outputs (no dot recompute in bwd — higher useful-FLOP ratio
-    # when HBM allows, see §Perf)
+    # when HBM allows)
     remat_policy: str = "nothing"
     # kernels/ops impl selectors: "auto" runs the Pallas attention kernel
     # on a TPU and the XLA reference elsewhere; the scan kernels stay on
@@ -162,7 +162,9 @@ class ModelConfig:
     # "fsdp": model axis = extra data/param shards (best for small-to-mid
     # models at large batch); "tp": Megatron activation sharding on the
     # model axis (needed when per-layer weights dwarf activations, e.g.
-    # DeepSeek-V2's 160-expert layers where EP is mandatory).
+    # DeepSeek-V2's 160-expert layers where EP is mandatory).  Nothing
+    # reads it yet: the activation constraints in models/common.py are
+    # fixed at "tp", and launch/sharding.py takes its own ``profile``.
     sharding_profile: str = "fsdp"
 
     def __post_init__(self):

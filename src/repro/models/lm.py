@@ -159,8 +159,7 @@ def _apply_attn_layer(cfg: ModelConfig, kind: str, ffn_kind: str, lp: Dict,
         return x + a + f, stats
     # pin the residual to batch-only sharding at the psum point: without
     # this GSPMD keeps x d_model-sharded and re-gathers it (in f32) for
-    # every consumer — ~3 redundant (B,S,D) all-gathers per layer on the
-    # tp profile (EXPERIMENTS §Perf it. 12).
+    # every consumer — ~3 redundant (B,S,D) all-gathers per layer.
     x = constrain_batch(x + a)
     h = apply_norm(cfg, lp["ln2"], x)
     f, stats = _apply_ffn(cfg, ffn_kind, lp["ffn"], h, serve)

@@ -1,6 +1,6 @@
 """Whisper-style encoder-decoder (audio backbone; conv frontend stubbed).
 
-``input_specs`` for this arch provides precomputed frame embeddings
+The batch carries precomputed frame embeddings, ``frames`` of shape
 (B, n_audio_ctx, d_model) — the mel-spectrogram conv stem is the modality
 frontend and out of scope per the assignment.  Everything downstream is
 real: sinusoidal encoder positions, bidirectional encoder self-attention,

@@ -7,11 +7,9 @@ step (error feedback), which keeps SGD/Adam convergence intact in practice.
 
 Under GSPMD the data/model-axis reductions are emitted by XLA, so this
 codec is applied at the optimizer boundary (quantize -> dequantize + error
-state).  The bandwidth saving applies when the launcher routes the pod-axis
-reduction through :func:`compressed_psum` inside a shard_map block; on this
-CPU container we validate the numerics (round-trip error, error-feedback
-accumulation) and count the 4x byte reduction in the roofline's collective
-term when the flag is on.
+state).  The bandwidth saving needs the pod-axis reduction routed through
+the codec inside a shard_map block, which no launcher does yet; the tests
+validate the numerics (round-trip error, error-feedback accumulation).
 """
 
 from __future__ import annotations

@@ -54,8 +54,8 @@ class TrainerConfig:
     straggler_factor: float = 3.0
     restore: bool = True
     #: overlap checkpoint saves with step compute (save_async); False runs
-    #: every save synchronously on the training thread (the serial baseline
-    #: benchmarks/bench_write.py measures against)
+    #: every save synchronously on the training thread (the serial
+    #: baseline)
     write_behind: bool = True
     #: retention policy installed on the CheckpointManager at fit() time
     #: (None keeps whatever the manager was built with); every save is

@@ -119,7 +119,7 @@ class StagingTxn:
         self._records: List[StageRecord] = []
         self._staged_fds: Dict[int, StageRecord] = {}
         self._seq = itertools.count()
-        # observability (tests, bench_write)
+        # observability (tests)
         self.published_count = 0
         self.undone_count = 0
         self.rollback_errors: List[BaseException] = []

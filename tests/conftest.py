@@ -1,6 +1,6 @@
 import os
 import sys
 
-# Tests must see the real single-CPU device (the 512-device override is
-# exclusively for the dry-run, per the assignment).
+# Tests see the real single-CPU device; a test that needs more host devices
+# runs them in a child process.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

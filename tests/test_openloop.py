@@ -1,12 +1,13 @@
 """Deterministic open-loop scheduler harness (the 10k-session-scale test).
 
-The open-loop benchmark (benchmarks/bench_openloop.py) drives the shared
-backend with wall-clock Poisson arrivals and 32 real server threads — great
-for measuring the saturation knee, useless as a CI regression test (timing
-noise, sleeps, machine-dependent capacity).  This module replays the *same
-seeded arrival traces* (:func:`repro.launch.ioserver.arrival_schedule`)
-through the *same scheduler machinery* (``SlotScheduler`` +
-``SharedBackend`` views + the completion pool) with:
+The open-loop server (``repro.launch.ioserver.run_openloop``) drives the
+shared backend with wall-clock Poisson arrivals and real server threads —
+fit for finding the saturation knee, useless as a CI regression test
+(timing noise, sleeps, machine-dependent capacity).  This module replays
+the *same seeded arrival traces*
+(:func:`repro.launch.ioserver.arrival_schedule`) through the *same
+scheduler machinery* (``SlotScheduler`` + ``SharedBackend`` views + the
+completion pool) with:
 
 * a :class:`ManualPlane` — an :class:`repro.core.backends.IOPlane` with no
   worker threads: admitted requests queue on a deque and execute only when

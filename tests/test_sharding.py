@@ -107,7 +107,7 @@ def test_batch_spec_fallback_chain():
 
 def test_embed_not_fsdp_sharded_on_dmodel():
     """Regression: sharding the embedding's d_model over "data" made XLA
-    psum (B,C,V) logits chunks — ~190 GiB/device (EXPERIMENTS §Perf it.1)."""
+    psum (B,C,V) logits chunks — ~190 GiB/device on a 16x16 mesh."""
     cfg = get_config("gemma_2b")
     model = build_model(cfg)
     sds = jax.eval_shape(model.init, jax.random.PRNGKey(0))

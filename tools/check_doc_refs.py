@@ -30,7 +30,8 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 PATH_RE = re.compile(
-    r"\b(?:src|tests|benchmarks|docs|examples|tools)/[\w./-]+\.(?:py|md|json|yml)\b"
+    r"\b(?:src|tests|benchmarks|tpubench|docs|examples|tools)/[\w./-]+"
+    r"\.(?:py|md|json|yml)\b"
 )
 MODULE_RE = re.compile(r"\brepro(?:\.\w+)+\b")
 DOT_BLOCK_RE = re.compile(r"^```dot\n(.*?)^```", re.MULTILINE | re.DOTALL)
@@ -45,13 +46,6 @@ DOT_LINE_RES = [
                r'(?: \[(?:style=dashed)?(?:, )?(?:label="loop \d+")?\])?;$'),
     re.compile(r"^\}$"),
 ]
-
-#: paths docs may legitimately reference before they exist at check time
-GENERATED = {"benchmarks/results/sharding.json",
-             "benchmarks/results/adaptive.json",
-             "benchmarks/results/serve.json",
-             "benchmarks/results/write.json"}
-
 
 def _buildable_dots() -> dict:
     """to_dot() renderings of every graph the repo can build, keyed by a
@@ -141,8 +135,6 @@ def check(path: str) -> list:
         text = f.read()
     missing = []
     for ref in sorted(set(PATH_RE.findall(text))):
-        if ref in GENERATED:
-            continue
         if not os.path.exists(os.path.join(REPO, ref)):
             missing.append(ref)
     for ref in sorted(set(MODULE_RE.findall(text))):
